@@ -21,8 +21,8 @@ Workloads:
     remains the right CPU backend for write-heavy *simulation*.)
   * PageRank on a Barabási–Albert graph through `GraphSession(backend=...)`
     with the cost model off (`account=False`) — the pure execution path a
-    device deployment runs, won via the cached routing permutation +
-    scatter-free prefix-sum combine — and once with it on, to show the
+    device deployment runs, via the cached routing permutation + sorted
+    segment-sum combine — and once with it on, to show the
     end-to-end simulator also benefits.
 
   * Skewed ragged multiget (`backend/multiget/...`): Zipf-keyed batches

@@ -24,8 +24,12 @@ deterministic metrics are rerun-stable and regression-diffable — see
 from __future__ import annotations
 
 import argparse
+import os
+import pathlib
 import sys
 import time
+
+import jax
 
 from . import (bench_ablation, bench_backend, bench_breakdown, bench_elastic,
                bench_graph, bench_kernels, bench_paramserve, bench_plan,
@@ -52,6 +56,12 @@ SUITES = {
 
 
 def main() -> None:
+    # JAX reads JAX_COMPILATION_CACHE_DIR itself; otherwise keep compiled
+    # programs at one fixed path (the path is part of the cache key)
+    if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+        jax.config.update(
+            "jax_compilation_cache_dir",
+            str(pathlib.Path(__file__).resolve().parent.parent / ".jax_cache"))
     ap = argparse.ArgumentParser()
     ap.add_argument("--suite", default="all", choices=["all", *SUITES])
     ap.add_argument("--quick", action="store_true")

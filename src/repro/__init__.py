@@ -1,4 +1,3 @@
 # TD-Orch reproduction: task-data orchestration (repro.core), the §4/§5 case
 # studies (repro.kvstore, repro.graph), and the JAX/Pallas production stack
 # (repro.models, repro.launch, repro.kernels, repro.runtime).
-from . import _jax_compat  # noqa: F401  (cross-version jax aliases)

@@ -34,11 +34,14 @@ tolerance. `tests/test_backend_parity.py` pins this for all four engines.
 
 Lambdas under the jax backend are traced with jnp arrays; a lambda that is
 not traceable (calls numpy on its inputs, data-dependent control flow) is
-detected on first use and permanently routed to the numpy path for that
-function object — correctness never depends on traceability. Jitted programs
-are cached per (lambda object, shape signature): reuse the same function
-object across stages (module-level lambdas, not per-call closures) to avoid
-retracing.
+detected on first use — by the tracer errors JAX raises for exactly that
+(`jaxexec.UNTRACEABLE`) — and permanently routed to the numpy path for that
+function object; `host_stages` counts every stage that ran there. Any
+other failure of a jitted stage or a kernel (a compile error, a runtime
+error) raises: the device path never degrades to the host in silence.
+Jitted programs are cached per (lambda object, shape signature): reuse the
+same function object across stages (module-level lambdas, not per-call
+closures) to avoid retracing.
 """
 from __future__ import annotations
 
@@ -89,6 +92,8 @@ class NumpyBackend:
     # plan flushes). Always 0 here — the oracle IS host-resident; the jax
     # backend counts, and `benchmarks/bench_plan.py` reports syncs/round.
     host_syncs = 0
+    # stages a device backend ran in the numpy oracle instead (always 0 here)
+    host_stages = 0
 
     # -- StagePlan device-residency hooks (no-ops for the host oracle) ------
     def begin_plan(self, store) -> None:
@@ -205,6 +210,9 @@ class JaxBackend(NumpyBackend):
         # host↔device transfer counter (results / combined write-backs /
         # plan flushes) — what bench_plan reports as syncs-per-round
         self.host_syncs = 0
+        # stages whose numerics ran in the numpy oracle (untraceable lambda,
+        # keys past int32): a device run that means it asserts this is 0
+        self.host_stages = 0
         # StagePlan device-residency scope (core/plan.py): while a plan runs
         # over `_plan_store`, write-backs stay on device and the host copy is
         # refreshed lazily at flush points (before user callbacks, plan exit)
@@ -252,6 +260,13 @@ class JaxBackend(NumpyBackend):
         store.write_rows(wk, rows)
         self._remember_values(store, dv)
 
+    def _host_stage(self, tasks, store, f):
+        """Run one stage's numerics in the numpy oracle, counted."""
+        if tasks.n:
+            self.host_stages += 1
+        self._flush_if_deferred(store)
+        return execution.execute(tasks, store, f)
+
     def _flush_if_deferred(self, store) -> None:
         """Host code is about to read/write `store.values` directly: make
         the host copy current first."""
@@ -272,6 +287,12 @@ class JaxBackend(NumpyBackend):
         store.__dict__.setdefault("_device_values", {})[self.dtype] = (
             store.version, dv)
 
+    def resident(self, store):
+        """The device array holding `store`'s values for this backend
+        (None before its first stage over `store`)."""
+        ent = store.__dict__.get("_device_values", {}).get(self.dtype)
+        return None if ent is None else ent[1]
+
     def _di(self, arr):
         return self._jnp.asarray(np.asarray(arr).astype(np.int32, copy=False))
 
@@ -290,10 +311,9 @@ class JaxBackend(NumpyBackend):
         tasks.__dict__["_device_ctx"] = (self.dtype, self._jnp.asarray(ctx_np))
 
     def sync(self, store=None) -> None:
-        if store is not None:
-            ent = store.__dict__.get("_device_values", {}).get(self.dtype)
-            if ent is not None:
-                self._jax.block_until_ready(ent[1])
+        dv = None if store is None else self.resident(store)
+        if dv is not None:
+            self._jax.block_until_ready(dv)
 
     # -- phase 3 (+ fused phase-4 ⊗) ---------------------------------------
     def execute(self, tasks, store, f: Callable, merge: Optional[MergeOp] = None,
@@ -302,8 +322,7 @@ class JaxBackend(NumpyBackend):
         self._stash = None
         if tasks.n == 0 or id(f) in self._host_lambdas \
                 or store.num_keys >= 2**30:
-            self._flush_if_deferred(store)
-            return execution.execute(tasks, store, f)
+            return self._host_stage(tasks, store, f)
 
         n = tasks.n
         # when there ARE writers but no fused combine, the engines need the
@@ -336,12 +355,11 @@ class JaxBackend(NumpyBackend):
                 return self._execute_fused(
                     tasks, store, f.fused_spec, merge, merge_name, combine,
                     want_update, want_result, w_rows)
-            except Exception:
+            except self._jx.UNTRACEABLE:
                 # untraceable finish epilogue: same permanent per-lambda
                 # fallback as the padded path below
                 self._host_lambdas.add(id(f))
-                self._flush_if_deferred(store)
-                return execution.execute(tasks, store, f)
+                return self._host_stage(tasks, store, f)
 
         # plan scope: pad the batch to a bucketed static shape so rounds
         # with drifting sizes share compiled executables. Padding rows read
@@ -387,13 +405,12 @@ class JaxBackend(NumpyBackend):
                     dv, self._di(tasks.read_indices), self._di(row),
                     self._di(col), self._jnp.asarray(mask), ctx,
                     self._di(w_idx), self._di(seg), self._di(order), **kw)
-        except Exception:
+        except self._jx.UNTRACEABLE:
             # untraceable lambda (numpy calls on tracers, data-dependent
-            # control flow, ...): route this function object to the oracle
-            # path from now on — if it is genuinely broken it raises there
+            # control flow): route this function object to the oracle path
+            # from now on — if it is genuinely broken it raises there
             self._host_lambdas.add(id(f))
-            self._flush_if_deferred(store)
-            return execution.execute(tasks, store, f)
+            return self._host_stage(tasks, store, f)
 
         host: Dict[str, Optional[np.ndarray]] = {"result": None,
                                                  "update": None}
@@ -570,11 +587,11 @@ class JaxBackend(NumpyBackend):
     # -- DistEdgeMap local combine ------------------------------------------
     def combine_by_key(self, values, keys, num_keys, merge: MergeOp, order):
         """Add-combines over a *repeated* key set (PageRank re-reduces the
-        same edge list every round) run scatter-free on device via the
-        cached routing permutation; everything else — first sighting of a
-        key set, non-add merges, tiny batches — uses the oracle path. The
-        returned key list is identical either way; combined sums agree
-        within float32 prefix-sum tolerance."""
+        same edge list every round) run on device as a sorted segment sum
+        over the cached routing permutation; everything else — first
+        sighting of a key set, non-add merges, tiny batches — uses the
+        oracle path. The returned key list is identical either way;
+        combined sums agree within float32 tolerance."""
         if merge.name == "add" and keys.size >= 4096 and num_keys < 2**31:
             rt = self._route
             if (rt is not None and rt[0].size == keys.size
@@ -584,13 +601,13 @@ class JaxBackend(NumpyBackend):
                     # investment pays off (a one-shot key set never sorts
                     # twice, it only pays the O(m) copy + compare)
                     perm = np.argsort(keys, kind="stable")
-                    sk = keys[perm]
-                    ends = np.flatnonzero(np.r_[sk[1:] != sk[:-1], True])
-                    rt = self._route = (rt[0], self._di(perm), self._di(ends),
-                                        sk[ends].astype(np.int64))
+                    uniq, seg = np.unique(keys[perm], return_inverse=True)
+                    rt = self._route = (rt[0], self._di(perm), self._di(seg),
+                                        uniq.astype(np.int64))
                 dev = self._jx.sorted_segment_sum(
                     self._jnp.asarray(np.asarray(values).astype(
-                        self._np_dtype, copy=False)), rt[1], rt[2])
+                        self._np_dtype, copy=False)), rt[1], rt[2],
+                    num_segments=rt[3].size)
                 self.host_syncs += 1
                 return rt[3].copy(), np.asarray(dev).astype(np.float64)
             self._route = (keys.copy(),)  # candidate; build routing if seen again
@@ -646,6 +663,12 @@ class SpmdBackend(JaxBackend):
         out, self.stage_stats = self.stage_stats, []
         return out
 
+    def resident(self, store):
+        """The (P, slab_rows, w) array sharded over the mesh — shard m holds
+        the chunks machine m homes (None before the first stage)."""
+        ent = store.__dict__.get("_spmd_values", {}).get(str(self._np_dtype))
+        return None if ent is None else ent[1]
+
     def prefetch(self, tasks, store) -> None:
         """Sharded stages materialize per-shard operands inside the stage
         program from the host copy — there is no whole-batch device upload
@@ -659,8 +682,7 @@ class SpmdBackend(JaxBackend):
         self._sx.get_mesh(store.P)  # device-count failure must not degrade
         if tasks.n == 0 or id(f) in self._host_lambdas \
                 or store.num_keys >= 2**30:
-            self._flush_if_deferred(store)
-            return execution.execute(tasks, store, f)
+            return self._host_stage(tasks, store, f)
         w_rows, combine, want_update = _combine_eligibility(tasks, merge)
         self._flush_if_deferred(store)  # slabs materialize from host values
         try:
@@ -668,13 +690,14 @@ class SpmdBackend(JaxBackend):
                 self, tasks, store, f, merge, want_result, combine,
                 want_update, exec_site, replicas)
         except self._sx.ShardStageError:
-            # untraceable lambda / unshardable update shape: permanently
-            # route this function object to the oracle path (genuinely
-            # broken lambdas raise there, with a host traceback). Host-side
-            # placement/layout failures are NOT caught — they propagate as
-            # the bugs they are instead of silently unsharding the run.
+            # untraceable lambda: permanently route this function object to
+            # the oracle path (genuinely broken lambdas raise there, with a
+            # host traceback). Compile/runtime failures of the stage program
+            # and host-side placement/layout failures are NOT caught — they
+            # propagate as the bugs they are instead of silently unsharding
+            # the run.
             self._host_lambdas.add(id(f))
-            return execution.execute(tasks, store, f)
+            return self._host_stage(tasks, store, f)
         self.stage_stats.append(out["stats"])
         host: Dict[str, Optional[np.ndarray]] = {"result": out["result"],
                                                  "update": out["update"]}

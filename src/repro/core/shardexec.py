@@ -45,7 +45,6 @@ from typing import Dict, NamedTuple, Optional
 
 import numpy as np
 
-from .. import _jax_compat  # noqa: F401 — ensures jax.shard_map exists
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -56,8 +55,8 @@ from . import execution
 # (one shared definition, so the two can never disagree on bucket shapes)
 from .backend import _bucket_rows as _bucket
 from .datastore import stable_bucket_slots
-from .jaxexec import (_as_update_rows, _segment_combine, bucket_routing,
-                      detect_contention, gather_from_buckets,
+from .jaxexec import (UNTRACEABLE, _as_update_rows, _segment_combine,
+                      bucket_routing, detect_contention, gather_from_buckets,
                       scatter_to_buckets)
 
 AXIS = "shards"
@@ -65,9 +64,9 @@ _IMAX = np.int32(np.iinfo(np.int32).max)
 
 
 class ShardStageError(RuntimeError):
-    """The compiled sharded stage failed to trace or run — the
-    fallback-eligible class of failures (untraceable lambda, unsupported
-    update shape). Host-side placement/layout errors are deliberately NOT
+    """The stage lambda could not be traced (`jaxexec.UNTRACEABLE`) — the
+    one fallback-eligible failure. Compile and runtime errors of the stage
+    program and host-side placement/layout errors are deliberately NOT
     wrapped: those are bugs, and silently degrading to an unsharded run
     would invalidate every per-machine claim."""
 
@@ -496,11 +495,11 @@ def run_sharded_stage(backend, tasks, store, f, merge,
         res_d, upd_d, new_slabs, rep_new, stats_d = prog(
             slabs, ctx, valid, wk, order, grow, pkey, prow, pcol, mask,
             owner_ext, slot_ext, rep_ids, rep_lookup_ext, rep_slab)
-    except Exception as e:
-        # only the traced program is fallback-eligible (mirrors the jax
+    except UNTRACEABLE as e:
+        # only an untraceable lambda is fallback-eligible (mirrors the jax
         # backend, whose try covers exactly the jitted stage call)
         raise ShardStageError(
-            f"sharded stage failed to trace/run: {e}") from e
+            f"sharded stage lambda is not traceable: {e}") from e
 
     stats_np = np.asarray(stats_d)
     stats = ShardStageStats(*(stats_np[:, i].astype(np.int64)

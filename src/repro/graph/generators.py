@@ -65,7 +65,8 @@ def erdos_renyi(n: int, avg_degree: float, seed: int = 0) -> Graph:
 
 def barabasi_albert(n: int, attach: int = 8, seed: int = 0) -> Graph:
     """Preferential attachment — power-law (skewed) degree distribution.
-    Uses the repeated-nodes sampling trick: O(m) expected time."""
+    Uses the repeated-nodes sampling trick in one preallocated buffer:
+    O(m) time."""
     rng = np.random.default_rng(seed)
     if n <= attach:
         raise ValueError("n must exceed attach count")
@@ -77,15 +78,20 @@ def barabasi_albert(n: int, attach: int = 8, seed: int = 0) -> Graph:
             srcs.append(v)
             dsts.append(u)
             repeated += [u, v]
-    rep = np.array(repeated, dtype=np.int64)
+    rep = np.empty(len(repeated) + 2 * attach * (n - attach - 1),
+                   dtype=np.int64)
+    rep[:len(repeated)] = repeated
+    size = len(repeated)
     out_s = [np.array(srcs, dtype=np.int64)]
     out_d = [np.array(dsts, dtype=np.int64)]
     for v in range(attach + 1, n):
-        targets = rep[rng.integers(0, rep.size, size=attach)]
-        targets = np.unique(targets)
-        out_s.append(np.full(targets.size, v, dtype=np.int64))
+        targets = np.unique(rep[rng.integers(0, size, size=attach)])
+        k = targets.size
+        out_s.append(np.full(k, v, dtype=np.int64))
         out_d.append(targets)
-        rep = np.concatenate([rep, targets, np.full(targets.size, v, dtype=np.int64)])
+        rep[size:size + k] = targets
+        rep[size + k:size + 2 * k] = v
+        size += 2 * k
     return _dedup_symmetrize(n, np.concatenate(out_s), np.concatenate(out_d))
 
 

@@ -6,7 +6,6 @@
 #   segment_combine — merge-able ⊗-combine (TD-Orch Phase 4 / DistEdgeMap)
 #   mamba_scan      — Mamba2 SSD chunk scan (zamba2 backbone)
 #   flash_decode    — single-token decode attention over long KV caches
-from . import _compat  # noqa: F401  (pallas version-compat aliases)
 from .flash_attention.ops import attention
 from .flash_decode.ops import decode_attention
 from .histogram.ops import count_ids

@@ -1,11 +1,13 @@
 """Public segment-combine op with backend selection.
 
 The Phase-4 merge-able ⊗: `combine_add` dispatches to the Pallas kernel on
-TPU (jnp fallback elsewhere); `combine` generalizes to the other
-set-associative merges from `core/mergeops.py` (min / max / or) as jnp
-scatter reductions with the same drop-out-of-range contract, so the jitted
-execution backend asks one op for every merge. Rows whose segment id is
->= num_segments are dropped — the static-shape encoding of "writes nothing".
+TPU while the shape fits the kernel's VMEM bound (`fits_pallas`), and to
+the jnp scatter otherwise — on the device either way; `combine`
+generalizes to the other set-associative merges from `core/mergeops.py`
+(min / max / or) as jnp scatter reductions with the same
+drop-out-of-range contract, so the jitted execution backend asks one op
+for every merge. Rows whose segment id is >= num_segments are dropped —
+the static-shape encoding of "writes nothing".
 """
 from __future__ import annotations
 
@@ -14,18 +16,30 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .kernel import segment_add
+from .. import routes
+from .kernel import acc_bytes, segment_add
 from .ref import segment_add_ref
+
+# Largest f32 accumulator (V_pad × W_pad) the kernel may keep in VMEM. AOT
+# compiles for a v5e (16 MiB default scoped VMEM, N=2^16 rows, blocks of
+# 256) pass at 6 MiB for W_pad of 128, 256 and 512 (V = 12288, 6144, 3072)
+# and fail at 7 MiB for W_pad of 256 and 512 (tests/test_tpu_compile.py
+# compiles at this bound).
+MAX_ACC_BYTES = 6 << 20
+
+
+def fits_pallas(num_segments: int, width: int) -> bool:
+    return acc_bytes(num_segments, width) <= MAX_ACC_BYTES
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments", "backend"))
 def combine_add(values, seg, num_segments: int, *, backend: str = "auto"):
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "ref"
-    if backend == "ref":
+    route = routes.pick("segment_add", backend,
+                        fits_pallas(num_segments, values.shape[1]))
+    if route.startswith("ref"):
         return segment_add_ref(values, seg, num_segments)
     return segment_add(values, seg, num_segments,
-                       interpret=(backend == "interpret"))
+                       interpret=(route == "interpret"))
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments", "op", "backend"))

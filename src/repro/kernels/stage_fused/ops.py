@@ -25,25 +25,42 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .kernel import fused_stage_pallas
+from .. import routes
+from .kernel import _rup, fused_stage_pallas, output_width
 from .ref import fused_stage_ref
 
 FUSED_READ_OPS = ("add", "min", "max", "first")
 FUSED_MERGES = ("add", "min", "max", "or", "write")
 
-# VMEM-budget bounds for the fused kernel: the whole value table and the
-# combine accumulator are VMEM-resident (≈ K·w·4 + S·w_out·4 bytes plus the
-# (block_p, K) gather onehot) — beyond these the jnp fallback wins anyway
-_MAX_KEYS = 1 << 13
-_MAX_WIDTH = 512
-_MAX_SEGMENTS = 1 << 13
-_MAX_NNZ = 1 << 21
+# Fast-memory bounds of the Pallas kernel on a v5e (16 MiB default scoped
+# VMEM, 1 MiB SMEM), fitted to AOT compiles (tests/test_tpu_compile.py
+# compiles at them). The value table rides VMEM single-buffered as three
+# bfloat16 parts: it compiles at 24 MiB (K = 32768 at w = 32, 16384 at
+# w = 250) and not at 48. The gather's bfloat16 onehot (K_pad × block_p)
+# and two f32 blocks of the combine (S_pad × wo_pad) share the scoped VMEM:
+# every compile with that sum at 12 MiB passed (K = 32768 with S = 4096;
+# K = 16384 with S = 8192), every one at 14 MiB or more failed. The pair
+# lists stream from HBM and have no bound. The per-task write segment and
+# order plus the tile bounds ride scalar prefetch in SMEM: 2^16 tasks
+# (0.6 MiB) compile, 2^17 (1.14 MiB) do not.
+TABLE_BUDGET = 24 << 20
+SCOPED_BUDGET = 12 << 20
+SMEM_BUDGET = 768 << 10
 
 
 def fits_pallas(num_keys: int, width: int, num_segments: int,
-                nnz: int) -> bool:
-    return (num_keys <= _MAX_KEYS and width <= _MAX_WIDTH
-            and num_segments <= _MAX_SEGMENTS and nnz <= _MAX_NNZ)
+                num_tasks: int, w_out: int | None = None,
+                block_p: int = 128) -> bool:
+    """Whether the fused kernel's VMEM and SMEM hold this stage."""
+    wo = width if w_out is None else w_out
+    k_pad = _rup(num_keys, 16)
+    table = 6 * k_pad * _rup(width, 128)
+    scoped = (2 * k_pad * block_p
+              + 8 * _rup(num_segments, 128) * _rup(wo, 128))
+    n_pad = _rup(num_tasks + 1, 8)
+    smem = 4 * (2 * n_pad + n_pad // 4 + _rup(num_segments, 128))
+    return (table <= TABLE_BUDGET and scoped <= SCOPED_BUDGET
+            and smem <= SMEM_BUDGET)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -73,13 +90,12 @@ def fused_stage(values, indptr, indices, pair_task, contexts, seg, order, *,
         raise KeyError(f"fused read op {read_op!r} not in {FUSED_READ_OPS}")
     if combine and merge_name not in FUSED_MERGES:
         raise KeyError(f"merge op {merge_name!r} has no fused combine")
-    if backend == "auto":
-        backend = "pallas" if (
-            jax.default_backend() == "tpu"
-            and fits_pallas(values.shape[0], values.shape[1],
-                            num_segments, int(np.asarray(indptr)[-1]))
-        ) else "ref"
-    if backend == "ref":
+    n = np.asarray(indptr).shape[0] - 1
+    c = int(contexts.shape[1]) if contexts.ndim > 1 else 0
+    w_out = output_width(finish, values.shape[1], c, block_t)
+    route = routes.pick("stage_fused", backend, fits_pallas(
+        values.shape[0], values.shape[1], num_segments, n, w_out, block_p))
+    if route.startswith("ref"):
         return _ref_jit(jnp.asarray(values), jnp.asarray(indptr),
                         jnp.asarray(indices), jnp.asarray(pair_task),
                         jnp.asarray(contexts), jnp.asarray(seg),
@@ -90,5 +106,5 @@ def fused_stage(values, indptr, indices, pair_task, contexts, seg, order, *,
                               seg, order, num_segments=num_segments,
                               read_op=read_op, finish=finish,
                               merge_name=merge_name, combine=combine,
-                              block_t=block_t, block_p=block_p,
-                              interpret=(backend == "interpret"))
+                              w_out=w_out, block_t=block_t, block_p=block_p,
+                              interpret=(route == "interpret"))

@@ -23,7 +23,6 @@ from typing import Dict, Optional
 import jax
 
 from ..configs import all_arch_ids, get_config
-from . import compat
 from .hlo import parse_collectives
 from .mesh import make_production_mesh
 from .specs import SHAPES, input_specs, shape_applicable  # noqa: F401
@@ -50,7 +49,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool,
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
         mem = compiled.memory_analysis()
-        cost = compat.cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         colls = parse_collectives(compiled.as_text())
         rec.update({
             "status": "ok",

@@ -4,7 +4,22 @@ XLA_FLAGS=--xla_force_host_platform_device_count=512 before first init;
 tests and benches see 1 device)."""
 from __future__ import annotations
 
-from .compat import make_mesh
+import jax
+
+
+def _auto(n_axes: int) -> dict:
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
+
+
+def make_mesh(shape, axes, **kw):
+    """`jax.make_mesh` with every axis in Auto sharding mode."""
+    return jax.make_mesh(tuple(shape), tuple(axes), **_auto(len(axes)), **kw)
+
+
+def abstract_mesh(shape, axes):
+    """A device-free `AbstractMesh` of the same shape and axis modes."""
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes),
+                                     **_auto(len(axes)))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -30,7 +30,6 @@ from typing import Dict, Optional
 import jax
 
 from ..configs import all_arch_ids, get_config
-from . import compat
 from .hlo import parse_collectives
 from .mesh import make_production_mesh
 from .specs import SHAPES, input_specs, shape_applicable
@@ -54,7 +53,7 @@ def _with_depth(cfg, n):
 def _costs_of(cfg, shape, mesh, overrides) -> Dict[str, float]:
     step = build_step(cfg, mesh, shape, scan_layers=False, **(overrides or {}))
     compiled = step.fn.lower(*step.arg_specs).compile()
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     colls = parse_collectives(compiled.as_text())
     return {
         "flops": float(cost.get("flops", 0.0)),

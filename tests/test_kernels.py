@@ -335,3 +335,58 @@ def test_mamba_matches_model_layer():
     y_ref = ssd_scan_ref(xs, dt, A, Bc, Cc)
     np.testing.assert_allclose(np.asarray(y_kernel), np.asarray(y_ref),
                                atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("jit", [False, True])
+def test_split3_is_lossless(jit):
+    """The three bfloat16 parts the gather/segment-sum kernels contract
+    sum back to the f32 value exactly, across magnitudes."""
+    from repro.kernels.onehot import split3
+
+    rng = np.random.default_rng(0)
+    x = (rng.standard_normal(4096)
+         * 10.0 ** rng.integers(-20, 20, 4096)).astype(np.float32)
+    parts = (jax.jit(split3) if jit else split3)(jnp.asarray(x))
+    assert all(p.dtype == jnp.bfloat16 for p in parts)
+    total = sum(np.asarray(p, np.float32) for p in parts)
+    np.testing.assert_array_equal(total, x)
+
+
+def _dot_operand_dtypes(jaxpr):
+    """Operand dtypes of every dot_general in `jaxpr` and its sub-jaxprs
+    (kernel bodies, pl.when branches)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.append(tuple(str(v.aval.dtype) for v in eqn.invars))
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (tuple, list)) else (val,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    found += _dot_operand_dtypes(inner)
+    return found
+
+
+@pytest.mark.parametrize("kernel", ["segment_add", "stage_fused"])
+def test_kernel_matmuls_take_bf16_parts(kernel):
+    """On a TPU an f32 x f32 matmul at default precision rounds both
+    operands to bfloat16 (interpret mode never shows it): every MXU
+    contraction in the onehot kernels must take bf16 operands, the values
+    split exactly by `split3`."""
+    from repro.kernels.stage_fused.kernel import fused_stage_pallas
+
+    rng = np.random.default_rng(1)
+    if kernel == "segment_add":
+        fn = lambda v, s: segment_add(v, s, 40, interpret=True)  # noqa: E731
+        args = (jnp.asarray(rng.standard_normal((300, 16)), jnp.float32),
+                jnp.asarray(rng.integers(0, 40, 300), jnp.int32))
+    else:
+        indptr = np.arange(0, 121, 4)
+        fn = lambda v, c: fused_stage_pallas(  # noqa: E731
+            v, indptr, rng.integers(0, 50, 120), np.repeat(np.arange(30), 4),
+            c, np.arange(30) % 7, np.arange(30), num_segments=7,
+            read_op="add", interpret=True)
+        args = (jnp.asarray(rng.standard_normal((50, 8)), jnp.float32),
+                jnp.zeros((30, 1), jnp.float32))
+    dots = _dot_operand_dtypes(jax.make_jaxpr(fn)(*args).jaxpr)
+    assert dots and all(d == ("bfloat16", "bfloat16") for d in dots), dots

@@ -11,7 +11,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import all_arch_ids, get_config, get_reduced
-from repro.launch.compat import abstract_mesh, make_mesh
+from repro.launch.mesh import abstract_mesh, make_mesh
 from repro.launch.specs import SHAPES, input_specs, shape_applicable
 
 
@@ -102,7 +102,7 @@ def test_reduced_config_compiles_on_small_mesh():
         import sys; sys.path.insert(0, "src")
         import jax, dataclasses
         from repro.configs import get_reduced
-        from repro.launch.compat import cost_analysis, make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.launch.steps import build_train_step
         from repro.launch.hlo import parse_collectives
         import repro.launch.specs as specs_mod
@@ -112,7 +112,7 @@ def test_reduced_config_compiles_on_small_mesh():
         cfg = get_reduced("granite-moe-1b-a400m")
         step = build_train_step(cfg, mesh, "train_4k", grad_accum=1)
         compiled = step.fn.lower(*step.arg_specs).compile()
-        assert cost_analysis(compiled).get("flops", 0) > 0
+        assert compiled.cost_analysis().get("flops", 0) > 0
         colls = parse_collectives(compiled.as_text())
         assert colls.count > 0  # EP all_to_all / psum must be present
         print("OK", int(colls.count))

@@ -132,7 +132,7 @@ def test_multidevice_shard_map_equivalence():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.core.spmd import MoEDispatchConfig, moe_push_pull, moe_reference
-        from repro.launch.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((4,), ("model",))
         rng = np.random.default_rng(1)
         T, d, f, E, k, ep = 128, 16, 32, 8, 2, 4

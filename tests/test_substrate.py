@@ -125,7 +125,7 @@ class TestCheckpoint:
             sys.path.insert(0, "src")
             from jax.sharding import PartitionSpec as P, NamedSharding
             from repro.checkpoint import save_checkpoint, restore_checkpoint
-            from repro.launch.compat import make_mesh
+            from repro.launch.mesh import make_mesh
             tree = {{"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}}
             path = save_checkpoint({str(tmp_path)!r}, 1, tree)
             mesh = make_mesh((4,), ("data",))
