@@ -1,0 +1,8 @@
+"""Bytes moved between host and device per batch of the window, in MB
+(1e6 B), from the execution backend's own counter
+(`JaxBackend.transfer_bytes`, core/backend.py)."""
+from program_metrics import counter_per
+
+
+def read(ctx):
+    return counter_per(ctx, "transfer_bytes", ctx.calls, 1e-6)

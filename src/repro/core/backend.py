@@ -52,6 +52,7 @@ import numpy as np
 from . import execution
 from .mergeops import MergeOp
 from .registry import get_backend_cls, register_backend
+from .spans import span
 
 # merges the jitted combine path implements; anything else falls back to the
 # oracle apply (still correct, just not fused)
@@ -92,6 +93,8 @@ class NumpyBackend:
     # plan flushes). Always 0 here — the oracle IS host-resident; the jax
     # backend counts, and `benchmarks/bench_plan.py` reports syncs/round.
     host_syncs = 0
+    # bytes moved between host and device, both ways (always 0 here)
+    transfer_bytes = 0
     # stages a device backend ran in the numpy oracle instead (always 0 here)
     host_stages = 0
 
@@ -210,6 +213,10 @@ class JaxBackend(NumpyBackend):
         # host↔device transfer counter (results / combined write-backs /
         # plan flushes) — what bench_plan reports as syncs-per-round
         self.host_syncs = 0
+        # bytes moved between host and device: every upload (store values on
+        # a cache miss, contexts, keys, edge values) and every blocking fetch
+        # (results, combined write-backs, flushes, histograms)
+        self.transfer_bytes = 0
         # stages whose numerics ran in the numpy oracle (untraceable lambda,
         # keys past int32): a device run that means it asserts this is 0
         self.host_stages = 0
@@ -254,10 +261,11 @@ class JaxBackend(NumpyBackend):
         # of re-specializing XLA's eager gather every round
         wk_pad = np.full(_bucket_rows(wk.size), wk[0], dtype=np.int64)
         wk_pad[:wk.size] = wk
-        rows = np.asarray(dv[self._jnp.asarray(wk_pad)])[:wk.size].astype(
+        rows = self._fetch(dv[self._upload(wk_pad)])[:wk.size].astype(
             store.values.dtype, copy=False)
         self.host_syncs += 1
-        store.write_rows(wk, rows)
+        with span("backend.writeback"):
+            store.write_rows(wk, rows)
         self._remember_values(store, dv)
 
     def _host_stage(self, tasks, store, f):
@@ -279,7 +287,9 @@ class JaxBackend(NumpyBackend):
         ent = cache.get(self.dtype)
         if ent is not None and ent[0] == store.version:
             return ent[1]
-        dv = self._jnp.asarray(store.values.astype(self._np_dtype, copy=False))
+        with span("backend.upload",
+                  bytes=store.values.size * self._np_dtype.itemsize):
+            dv = self._upload(store.values.astype(self._np_dtype, copy=False))
         cache[self.dtype] = (store.version, dv)
         return dv
 
@@ -293,8 +303,21 @@ class JaxBackend(NumpyBackend):
         ent = store.__dict__.get("_device_values", {}).get(self.dtype)
         return None if ent is None else ent[1]
 
+    def _upload(self, arr):
+        """Host → device copy, counted in `transfer_bytes`."""
+        dev = self._jnp.asarray(arr)
+        self.transfer_bytes += dev.nbytes
+        return dev
+
+    def _fetch(self, dev) -> np.ndarray:
+        """Blocking device → host read, counted in `transfer_bytes`."""
+        with span("backend.fetch"):
+            out = np.asarray(dev)
+        self.transfer_bytes += out.nbytes
+        return out
+
     def _di(self, arr):
-        return self._jnp.asarray(np.asarray(arr).astype(np.int32, copy=False))
+        return self._upload(np.asarray(arr).astype(np.int32, copy=False))
 
     # -- non-blocking dispatch hooks ----------------------------------------
     def prefetch(self, tasks, store) -> None:
@@ -308,7 +331,7 @@ class JaxBackend(NumpyBackend):
         if tasks.n == 0:
             return
         ctx_np = np.asarray(tasks.contexts).astype(self._np_dtype, copy=False)
-        tasks.__dict__["_device_ctx"] = (self.dtype, self._jnp.asarray(ctx_np))
+        tasks.__dict__["_device_ctx"] = (self.dtype, self._upload(ctx_np))
 
     def sync(self, store=None) -> None:
         dv = None if store is None else self.resident(store)
@@ -325,25 +348,26 @@ class JaxBackend(NumpyBackend):
             return self._host_stage(tasks, store, f)
 
         n = tasks.n
-        # when there ARE writers but no fused combine, the engines need the
-        # real update rows for the oracle apply (want_update)
-        w_rows, combine, want_update = _combine_eligibility(tasks, merge)
-        pr = tasks.priority
-        uniq = None
-        if combine:
-            uniq, seg_w = np.unique(tasks.write_keys[w_rows],
-                                    return_inverse=True)
-            B = _bucket_rows(w_rows.size)
-            w_idx = np.full(B, n, dtype=np.int32)
-            w_idx[:w_rows.size] = w_rows
-            seg = np.full(B, B, dtype=np.int32)
-            seg[:w_rows.size] = seg_w
-            order = np.zeros(B, dtype=np.int32)
-            order[:w_rows.size] = pr[w_rows]
-        else:
-            w_idx = np.zeros(1, dtype=np.int32)
-            seg = order = w_idx
-        merge_name = merge.name if combine else "add"
+        with span("backend.prepare"):
+            # when there ARE writers but no fused combine, the engines need
+            # the real update rows for the oracle apply (want_update)
+            w_rows, combine, want_update = _combine_eligibility(tasks, merge)
+            pr = tasks.priority
+            uniq = None
+            if combine:
+                uniq, seg_w = np.unique(tasks.write_keys[w_rows],
+                                        return_inverse=True)
+                B = _bucket_rows(w_rows.size)
+                w_idx = np.full(B, n, dtype=np.int32)
+                w_idx[:w_rows.size] = w_rows
+                seg = np.full(B, B, dtype=np.int32)
+                seg[:w_rows.size] = seg_w
+                order = np.zeros(B, dtype=np.int32)
+                order[:w_rows.size] = pr[w_rows]
+            else:
+                w_idx = np.zeros(1, dtype=np.int32)
+                seg = order = w_idx
+            merge_name = merge.name if combine else "add"
 
         # ragged batches with a fused-able lambda skip the padded gather
         # entirely: the stage_fused kernel family walks the CSR pair list
@@ -372,39 +396,46 @@ class JaxBackend(NumpyBackend):
                  and tasks.max_arity <= 1 else n)
 
         dv = self._device_values(store)
-        ctx_np = np.asarray(tasks.contexts).astype(self._np_dtype, copy=False)
-        if n_pad != n:
-            pad = np.zeros((n_pad,) + ctx_np.shape[1:], dtype=self._np_dtype)
-            pad[:n] = ctx_np
-            ctx_np = pad
-        pre = tasks.__dict__.pop("_device_ctx", None)
-        if n_pad == n and pre is not None and pre[0] == self.dtype:
-            ctx = pre[1]  # staged by prefetch(); already on device
-        else:
-            ctx = self._jnp.asarray(ctx_np)
-        fwd = execution._accepts_mask(f)
-        kw = dict(f=f, fwd_mask=fwd, merge_name=merge_name, combine=combine,
-                  want_update=want_update, want_result=want_result)
-        try:
+        with span("backend.prepare"):
+            ctx_np = np.asarray(tasks.contexts).astype(self._np_dtype,
+                                                       copy=False)
+            if n_pad != n:
+                pad = np.zeros((n_pad,) + ctx_np.shape[1:],
+                               dtype=self._np_dtype)
+                pad[:n] = ctx_np
+                ctx_np = pad
             if tasks.max_arity <= 1:
                 keys = tasks.read_keys
                 if n_pad != n:
                     kp = np.full(n_pad, -1, dtype=np.int64)
                     kp[:n] = keys
                     keys = kp
-                out = self._jx.run_stage_flat(
-                    dv, self._di(keys), ctx, self._di(w_idx),
-                    self._di(seg), self._di(order), **kw)
             else:
                 row = tasks.pair_task
                 col = np.arange(tasks.nnz, dtype=np.int64) \
                     - tasks.read_indptr[:-1][row]
                 mask = np.zeros((n_pad, tasks.max_arity), dtype=bool)
                 mask[row, col] = True
-                out = self._jx.run_stage_ragged(
-                    dv, self._di(tasks.read_indices), self._di(row),
-                    self._di(col), self._jnp.asarray(mask), ctx,
-                    self._di(w_idx), self._di(seg), self._di(order), **kw)
+        with span("backend.upload"):
+            pre = tasks.__dict__.pop("_device_ctx", None)
+            if n_pad == n and pre is not None and pre[0] == self.dtype:
+                ctx = pre[1]  # staged by prefetch(); already on device
+            else:
+                ctx = self._upload(ctx_np)
+            tail = (self._di(w_idx), self._di(seg), self._di(order))
+            if tasks.max_arity <= 1:
+                args = (dv, self._di(keys), ctx) + tail
+            else:
+                args = (dv, self._di(tasks.read_indices), self._di(row),
+                        self._di(col), self._upload(mask), ctx) + tail
+        fwd = execution._accepts_mask(f)
+        kw = dict(f=f, fwd_mask=fwd, merge_name=merge_name, combine=combine,
+                  want_update=want_update, want_result=want_result)
+        run = (self._jx.run_stage_flat if tasks.max_arity <= 1
+               else self._jx.run_stage_ragged)
+        try:
+            with span("backend.dispatch"):
+                out = run(*args, **kw)
         except self._jx.UNTRACEABLE:
             # untraceable lambda (numpy calls on tracers, data-dependent
             # control flow): route this function object to the oracle path
@@ -416,12 +447,12 @@ class JaxBackend(NumpyBackend):
                                                  "update": None}
         res_dev = out.get("result")
         if res_dev is not None:
-            host["result"] = np.asarray(
+            host["result"] = self._fetch(
                 res_dev[:n] if n_pad != n else res_dev)
             self.host_syncs += 1
         upd_dev = out.get("update")
         if upd_dev is not None:
-            host["update"] = np.asarray(
+            host["update"] = self._fetch(
                 upd_dev[:n] if n_pad != n else upd_dev)
             self.host_syncs += 1
         combined = out.get("combined")
@@ -446,47 +477,58 @@ class JaxBackend(NumpyBackend):
         placeholder tail as the padded path, so `apply_writes` is shared."""
         read_op, finish = spec
         n, nnz = tasks.n, tasks.nnz
-        uniq = None
-        if combine:
-            uniq, seg_w = np.unique(tasks.write_keys[w_rows],
-                                    return_inverse=True)
-            S = _bucket_rows(w_rows.size)
-        else:
-            S = 1
-        n_pad = _bucket_rows(n + 1)  # ≥ 1 pad task to absorb pad pairs
-        nnz_pad = _bucket_rows(nnz)
-        indptr_p = np.full(n_pad + 1, nnz, dtype=np.int64)
-        indptr_p[:n + 1] = tasks.read_indptr
-        indptr_p[n_pad] = nnz_pad  # the last pad task owns every pad pair
-        indices_p = np.zeros(nnz_pad, dtype=np.int64)
-        indices_p[:nnz] = tasks.read_indices
-        pt_p = np.full(nnz_pad, n_pad - 1, dtype=np.int64)
-        pt_p[:nnz] = tasks.pair_task
-        seg_t = np.full(n_pad, S, dtype=np.int32)  # S = writes nothing
-        order_t = np.zeros(n_pad, dtype=np.int32)
-        if combine:
-            seg_t[w_rows] = seg_w
-            order_t[:n] = tasks.priority  # int32-safe per eligibility check
+        with span("backend.prepare"):
+            uniq = None
+            if combine:
+                uniq, seg_w = np.unique(tasks.write_keys[w_rows],
+                                        return_inverse=True)
+                S = _bucket_rows(w_rows.size)
+            else:
+                S = 1
+            n_pad = _bucket_rows(n + 1)  # ≥ 1 pad task to absorb pad pairs
+            nnz_pad = _bucket_rows(nnz)
+            indptr_p = np.full(n_pad + 1, nnz, dtype=np.int64)
+            indptr_p[:n + 1] = tasks.read_indptr
+            indptr_p[n_pad] = nnz_pad  # the last pad task owns every pad pair
+            indices_p = np.zeros(nnz_pad, dtype=np.int64)
+            indices_p[:nnz] = tasks.read_indices
+            pt_p = np.full(nnz_pad, n_pad - 1, dtype=np.int64)
+            pt_p[:nnz] = tasks.pair_task
+            seg_t = np.full(n_pad, S, dtype=np.int32)  # S = writes nothing
+            order_t = np.zeros(n_pad, dtype=np.int32)
+            if combine:
+                seg_t[w_rows] = seg_w
+                order_t[:n] = tasks.priority  # int32-safe per eligibility
+            ctx_np = np.asarray(tasks.contexts).astype(self._np_dtype,
+                                                       copy=False)
+            ctx_pad = np.zeros((n_pad,) + ctx_np.shape[1:],
+                               dtype=self._np_dtype)
+            ctx_pad[:n] = ctx_np
         dv = self._device_values(store)
-        ctx_np = np.asarray(tasks.contexts).astype(self._np_dtype,
-                                                   copy=False)
-        ctx_pad = np.zeros((n_pad,) + ctx_np.shape[1:], dtype=self._np_dtype)
-        ctx_pad[:n] = ctx_np
         tasks.__dict__.pop("_device_ctx", None)  # padded: restage from host
-        out = self._jx.run_stage_fused(
-            dv, indptr_p, indices_p, pt_p, self._jnp.asarray(ctx_pad),
-            seg_t, order_t, num_segments=S, read_op=read_op, finish=finish,
-            merge_name=merge_name, combine=combine, want_update=want_update,
-            want_result=want_result,
-            kernel_backend=("interpret" if self.kernel_backend == "interpret"
-                            else "auto"))
+        with span("backend.upload"):
+            ctx = self._upload(ctx_pad)
+        # the kernel uploads its host CSR operands itself, as JAX's ints
+        self.transfer_bytes += (
+            self._jax.dtypes.canonicalize_dtype(np.int64).itemsize
+            * (indptr_p.size + indices_p.size + pt_p.size)
+            + seg_t.nbytes + order_t.nbytes)
+        with span("backend.dispatch"):
+            out = self._jx.run_stage_fused(
+                dv, indptr_p, indices_p, pt_p, ctx, seg_t, order_t,
+                num_segments=S, read_op=read_op, finish=finish,
+                merge_name=merge_name, combine=combine,
+                want_update=want_update, want_result=want_result,
+                kernel_backend=("interpret"
+                                if self.kernel_backend == "interpret"
+                                else "auto"))
         host: Dict[str, Optional[np.ndarray]] = {"result": None,
                                                  "update": None}
         if out["result"] is not None:
-            host["result"] = np.asarray(out["result"][:n])
+            host["result"] = self._fetch(out["result"][:n])
             self.host_syncs += 1
         if out["update"] is not None:
-            host["update"] = np.asarray(out["update"][:n])
+            host["update"] = self._fetch(out["update"][:n])
             self.host_syncs += 1
         combined = out["combined"]
         if combine and combined is not None:
@@ -542,8 +584,10 @@ class JaxBackend(NumpyBackend):
         uniq_pad = np.concatenate([
             uniq, np.arange(store.num_keys, store.num_keys + (B - uniq.size),
                             dtype=np.int64)])
-        new_dv = self._jx.apply_rows(dv, self._di(uniq_pad), combined_dev,
-                                     merge_name=merge.name)
+        uniq_dev = self._di(uniq_pad)
+        with span("backend.dispatch"):
+            new_dv = self._jx.apply_rows(dv, uniq_dev, combined_dev,
+                                         merge_name=merge.name)
         if self._plan_store is store:
             # plan scope: the write-back stays device-resident — the host
             # copy is refreshed at the next flush point (before any user
@@ -554,10 +598,11 @@ class JaxBackend(NumpyBackend):
             self._plan_dirty = True
             return
         # authoritative host apply (store dtype), exactly the oracle's ⊙
-        combined = np.asarray(combined_dev)[:uniq.size].astype(
-            store.values.dtype, copy=False)
-        self.host_syncs += 1
-        store.write_rows(uniq, merge.apply(store.values[uniq], combined))
+        with span("backend.writeback"):
+            combined = self._fetch(combined_dev)[:uniq.size].astype(
+                store.values.dtype, copy=False)
+            self.host_syncs += 1
+            store.write_rows(uniq, merge.apply(store.values[uniq], combined))
         self._remember_values(store, new_dv)
 
     # -- phase 1 ------------------------------------------------------------
@@ -570,7 +615,7 @@ class JaxBackend(NumpyBackend):
                 or num_keys >= 2**31:
             return super().key_counts(keys, num_keys, weights)
         w = None if weights is None else self._di(np.asarray(weights))
-        counts = np.asarray(self._jx.contention_counts(
+        counts = self._fetch(self._jx.contention_counts(
             self._di(keys), int(num_keys), weights=w,
             kernel_backend=("interpret"
                             if self.kernel_backend == "interpret"
@@ -580,8 +625,8 @@ class JaxBackend(NumpyBackend):
 
     # -- phase 2 ------------------------------------------------------------
     def argsort_stable(self, keys: np.ndarray) -> np.ndarray:
-        return np.asarray(
-            self._jx.stable_argsort(self._jnp.asarray(keys))
+        return self._fetch(
+            self._jx.stable_argsort(self._upload(keys))
         ).astype(np.int64)
 
     # -- DistEdgeMap local combine ------------------------------------------
@@ -593,10 +638,11 @@ class JaxBackend(NumpyBackend):
         oracle path. The returned key list is identical either way;
         combined sums agree within float32 tolerance."""
         if merge.name == "add" and keys.size >= 4096 and num_keys < 2**31:
-            rt = self._route
-            if (rt is not None and rt[0].size == keys.size
-                    and np.array_equal(rt[0], keys)):
-                if len(rt) == 1:
+            with span("backend.route"):
+                rt = self._route
+                seen = (rt is not None and rt[0].size == keys.size
+                        and np.array_equal(rt[0], keys))
+                if seen and len(rt) == 1:
                     # second sighting: the key set repeats — now the argsort
                     # investment pays off (a one-shot key set never sorts
                     # twice, it only pays the O(m) copy + compare)
@@ -604,12 +650,15 @@ class JaxBackend(NumpyBackend):
                     uniq, seg = np.unique(keys[perm], return_inverse=True)
                     rt = self._route = (rt[0], self._di(perm), self._di(seg),
                                         uniq.astype(np.int64))
-                dev = self._jx.sorted_segment_sum(
-                    self._jnp.asarray(np.asarray(values).astype(
-                        self._np_dtype, copy=False)), rt[1], rt[2],
-                    num_segments=rt[3].size)
+            if seen:
+                vals = np.asarray(values).astype(self._np_dtype, copy=False)
+                with span("backend.upload", bytes=vals.nbytes):
+                    vals_dev = self._upload(vals)
+                with span("backend.dispatch"):
+                    dev = self._jx.sorted_segment_sum(
+                        vals_dev, rt[1], rt[2], num_segments=rt[3].size)
                 self.host_syncs += 1
-                return rt[3].copy(), np.asarray(dev).astype(np.float64)
+                return rt[3].copy(), self._fetch(dev).astype(np.float64)
             self._route = (keys.copy(),)  # candidate; build routing if seen again
         return super().combine_by_key(values, keys, num_keys, merge, order)
 
@@ -731,9 +780,12 @@ class SpmdBackend(JaxBackend):
         # the owner shards already ⊙-applied to their slabs inside the
         # stage program; the authoritative host copy catches up with one
         # cross-shard gather of exactly the written rows
-        rows = self._sx.gather_slab_rows(store, new_slabs, uniq)
-        self.host_syncs += 1
-        store.write_rows(uniq, rows.astype(store.values.dtype, copy=False))
+        with span("backend.writeback"):
+            rows = self._fetch(self._sx.gather_slab_rows(store, new_slabs,
+                                                         uniq))
+            self.host_syncs += 1
+            store.write_rows(uniq, rows.astype(store.values.dtype,
+                                               copy=False))
         self._sx._pin_slabs(store, self._np_dtype, new_slabs)
         if rep_arrays is not None and replicas is not None:
             self._sx._pin_replicas(store, replicas, self._np_dtype,

@@ -44,6 +44,7 @@ from .datastore import DataStore, TaskBatch
 from .mergeops import MergeOp, get_merge_op
 from .registry import register_engine
 from .replication import ReplicaSet, charge_write_through
+from .spans import span
 
 # words charged per message row (header: key + level/count bookkeeping)
 _L0_HEADER = 2  # key + count
@@ -164,15 +165,16 @@ class TDOrchEngine:
         root_rows_cnt: np.ndarray = np.empty(0, dtype=np.int64)
 
         # ---------------- Phase 1: contention detection --------------------
-        cost.begin("phase1_contention_detection")
-        if tasks.nnz:
-            pair_site, root_rows_key, root_rows_cnt = self._phase1(
-                tasks, store, cost, stores, pair_site, sigma, C,
-                climb=~pair_local,
-            )
-        cost.end()
-        exec_site = tasks.origin.copy()
-        exec_site[has_read] = pair_site[tasks.read_indptr[:-1][has_read]]
+        with span("phase1"):
+            cost.begin("phase1_contention_detection")
+            if tasks.nnz:
+                pair_site, root_rows_key, root_rows_cnt = self._phase1(
+                    tasks, store, cost, stores, pair_site, sigma, C,
+                    climb=~pair_local,
+                )
+            cost.end()
+            exec_site = tasks.origin.copy()
+            exec_site[has_read] = pair_site[tasks.read_indptr[:-1][has_read]]
 
         # ---------------- Phase-3 work stealing (core/elasticity.py) -------
         # Rebalance exec-site assignment BEFORE Phase 2, so a stolen task's
@@ -190,40 +192,43 @@ class TDOrchEngine:
             cost.end()
 
         # ---------------- Phase 2: push-pull co-location -------------------
-        cost.begin("phase2_push_pull")
-        self._phase2_pull(store, cost, stores, B)
-        self._phase2_replica_local(tasks, store, cost, pair_local)
-        self._phase2_secondary(tasks, store, cost, pair_site, exec_site,
-                               replicas)
-        cost.end()
+        with span("phase2"):
+            cost.begin("phase2_push_pull")
+            self._phase2_pull(store, cost, stores, B)
+            self._phase2_replica_local(tasks, store, cost, pair_local)
+            self._phase2_secondary(tasks, store, cost, pair_site, exec_site,
+                                   replicas)
+            cost.end()
 
         # ---------------- Phase 3: execution -------------------------------
-        cost.begin("phase3_execute")
-        # want_result lets a device backend skip materializing per-task
-        # results the caller never asked for (a StagePlan round's only host
-        # traffic is then the write-back / flush path); exec_site/replicas
-        # let the mesh-sharded backend place real work exactly where the
-        # cost model just charged it
-        out = self.backend.execute(tasks, store, f, merge,
-                                   want_result=return_results,
-                                   exec_site=exec_site, replicas=replicas)
-        updates = out.get("update")
-        results = out.get("result")
-        cost.work(exec_site, self.work_per_task)
-        if self.work_per_pair and tasks.nnz:
-            cost.work(exec_site[tasks.pair_task], self.work_per_pair)
-        if return_results and results is not None:
-            w_r = results.shape[1] if results.ndim > 1 else 1
-            cost.send(exec_site, tasks.origin, w_r + 1)
-            cost.tick()
-        cost.end()
+        with span("phase3"):
+            cost.begin("phase3_execute")
+            # want_result lets a device backend skip materializing per-task
+            # results the caller never asked for (a StagePlan round's only
+            # host traffic is then the write-back / flush path); exec_site/
+            # replicas let the mesh-sharded backend place real work exactly
+            # where the cost model just charged it
+            out = self.backend.execute(tasks, store, f, merge,
+                                       want_result=return_results,
+                                       exec_site=exec_site, replicas=replicas)
+            updates = out.get("update")
+            results = out.get("result")
+            cost.work(exec_site, self.work_per_task)
+            if self.work_per_pair and tasks.nnz:
+                cost.work(exec_site[tasks.pair_task], self.work_per_pair)
+            if return_results and results is not None:
+                w_r = results.shape[1] if results.ndim > 1 else 1
+                cost.send(exec_site, tasks.origin, w_r + 1)
+                cost.tick()
+            cost.end()
 
         # ---------------- Phase 4: write-backs -----------------------------
-        cost.begin("phase4_write_back")
-        if updates is not None:
-            self._phase4(tasks, store, cost, stores, exec_site, updates, merge,
-                         replicas)
-        cost.end()
+        with span("phase4"):
+            cost.begin("phase4_write_back")
+            if updates is not None:
+                self._phase4(tasks, store, cost, stores, exec_site, updates,
+                             merge, replicas)
+            cost.end()
 
         refcount = {
             int(k): int(c) for k, c in zip(root_rows_key, root_rows_cnt) if c > 0

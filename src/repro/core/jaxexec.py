@@ -186,13 +186,17 @@ def run_stage_flat(values, keys, contexts, w_idx, seg, order, *, f,
     `w_idx` (B,) lists writer task rows padded with n to a bucket size B;
     `seg[j]` is writer j's write-segment id (B = dropped padding); `order`
     its priority for "write" merges."""
-    has = keys >= 0
-    gathered = jnp.where(has[:, None], values[jnp.clip(keys, 0)],
-                         jnp.zeros((), values.dtype))
-    out = f(contexts, gathered, has) if fwd_mask else f(contexts, gathered)
-    return _finish_stage(out, gathered, w_idx, seg, order,
-                         merge_name=merge_name, combine=combine,
-                         want_update=want_update, want_result=want_result)
+    with jax.named_scope("phase3_gather_lambda"):
+        has = keys >= 0
+        gathered = jnp.where(has[:, None], values[jnp.clip(keys, 0)],
+                             jnp.zeros((), values.dtype))
+        out = f(contexts, gathered, has) if fwd_mask else f(contexts,
+                                                            gathered)
+    with jax.named_scope("phase4_combine"):
+        return _finish_stage(out, gathered, w_idx, seg, order,
+                             merge_name=merge_name, combine=combine,
+                             want_update=want_update,
+                             want_result=want_result)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -205,12 +209,16 @@ def run_stage_ragged(values, read_indices, row, col, mask, contexts, w_idx,
     `run_stage_flat`."""
     n, A = mask.shape
     w = values.shape[1]
-    gathered = jnp.zeros((n, A, w), values.dtype).at[row, col].set(
-        values[read_indices], mode="drop")
-    out = f(contexts, gathered, mask) if fwd_mask else f(contexts, gathered)
-    return _finish_stage(out, gathered.reshape(n, A * w), w_idx, seg, order,
-                         merge_name=merge_name, combine=combine,
-                         want_update=want_update, want_result=want_result)
+    with jax.named_scope("phase3_gather_lambda"):
+        gathered = jnp.zeros((n, A, w), values.dtype).at[row, col].set(
+            values[read_indices], mode="drop")
+        out = f(contexts, gathered, mask) if fwd_mask else f(contexts,
+                                                             gathered)
+    with jax.named_scope("phase4_combine"):
+        return _finish_stage(out, gathered.reshape(n, A * w), w_idx, seg,
+                             order, merge_name=merge_name, combine=combine,
+                             want_update=want_update,
+                             want_result=want_result)
 
 
 def run_stage_fused(values, indptr, indices, pair_task, contexts, seg,
@@ -252,14 +260,15 @@ def apply_rows(values, uniq_padded, combined, *, merge_name: str):
     out-of-range keys (dropped) — sorted *and* unique, which XLA's scatter
     exploits; `combined` rows align with it."""
     kw = dict(mode="drop", unique_indices=True, indices_are_sorted=True)
-    if merge_name == "add":
-        return values.at[uniq_padded].add(combined, **kw)
-    if merge_name == "min":
-        return values.at[uniq_padded].min(combined, **kw)
-    if merge_name in ("max", "or"):
-        return values.at[uniq_padded].max(combined, **kw)
-    if merge_name == "write":
-        return values.at[uniq_padded].set(combined, **kw)
+    with jax.named_scope("phase4_apply"):
+        if merge_name == "add":
+            return values.at[uniq_padded].add(combined, **kw)
+        if merge_name == "min":
+            return values.at[uniq_padded].min(combined, **kw)
+        if merge_name in ("max", "or"):
+            return values.at[uniq_padded].max(combined, **kw)
+        if merge_name == "write":
+            return values.at[uniq_padded].set(combined, **kw)
     raise KeyError(f"merge op {merge_name!r} has no jax apply")
 
 

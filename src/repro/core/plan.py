@@ -64,6 +64,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable, Dict, List, Optional
 
+from .spans import span
+
 
 class _Carry:
     """Sentinel: "this stage consumes the loop's carried emission"."""
@@ -268,24 +270,27 @@ class _LoopOp:
             if max_r is not None and rounds >= max_r:
                 reason = "max_rounds"
                 break
-            body = self.body
-            if callable(body) and not isinstance(body, StagePlan):
-                body = rn.user(body, state)
-            rn.carry_touched = False
-            rn.run_ops(_as_ops(body), state, rounds)
-            if self.until == "empty" and not rn.carry_touched:
-                # no op in the body emitted a continuation, so the carried
-                # batch can never drain — re-running it forever is always a
-                # bug; fail loudly instead of hanging
-                raise RuntimeError(
-                    f"loop {self.name!r} (until='empty') made no progress: "
-                    "no stage in the body has emit= and no edge_map round "
-                    "ran, so the carried emission can never become empty. "
-                    "Add an emit= continuation, or use until=None with "
-                    "max_rounds= for a fixed-round loop.")
-            rounds += 1
-            state.round = rounds
-            if callable(self.until) and rn.user(self.until, state):
+            with span("plan.round", round=rounds):
+                body = self.body
+                if callable(body) and not isinstance(body, StagePlan):
+                    body = rn.user(body, state)
+                rn.carry_touched = False
+                rn.run_ops(_as_ops(body), state, rounds)
+                if self.until == "empty" and not rn.carry_touched:
+                    # no op in the body emitted a continuation, so the
+                    # carried batch can never drain — re-running it forever
+                    # is always a bug; fail loudly instead of hanging
+                    raise RuntimeError(
+                        f"loop {self.name!r} (until='empty') made no "
+                        "progress: no stage in the body has emit= and no "
+                        "edge_map round ran, so the carried emission can "
+                        "never become empty. Add an emit= continuation, or "
+                        "use until=None with max_rounds= for a fixed-round "
+                        "loop.")
+                rounds += 1
+                state.round = rounds
+                stop = callable(self.until) and rn.user(self.until, state)
+            if stop:
                 reason = "until"
                 break
         rn.loops.append(LoopRecord(self.name, rounds, reason))
@@ -395,12 +400,13 @@ class _PlanRunner:
         """Invoke a user callback (task/body factory, emit, until predicate,
         host step) with host state guaranteed fresh: a device-resident plan
         scope flushes pending write-backs to the host store first."""
-        bk = self.backend
-        if bk is not None:
-            flush = getattr(bk, "plan_flush", None)
-            if flush is not None:
-                flush()
-        return fn(*args)
+        with span("plan.host"):
+            bk = self.backend
+            if bk is not None:
+                flush = getattr(bk, "plan_flush", None)
+                if flush is not None:
+                    flush()
+            return fn(*args)
 
     def run_ops(self, ops: List[Any], state: PlanState,
                 round_idx: int) -> None:

@@ -19,6 +19,7 @@ throwaway session.
 from __future__ import annotations
 
 import inspect
+from contextlib import nullcontext
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
@@ -32,6 +33,7 @@ from .engine import OrchestrationResult
 from .mergeops import MergeOp
 from .registry import make_engine
 from .replication import make_replicator
+from .spans import span
 
 
 class Orchestrator:
@@ -187,22 +189,28 @@ class Orchestrator:
         bill; the work stealer is threaded into the engine's exec-site
         assignment; and the post-stage write-log/boundary bookkeeping runs
         last."""
+        with span("stage", stage=self._report.num_stages):
+            return self._run_stage(tasks, f, write_back, return_results)
+
+    def _run_stage(self, tasks, f, write_back, return_results):
+        boundary = self.elastic is not None or self.replicator is not None
         pre: List[StageReport] = []
-        if self.elastic is not None:
-            tasks = self.elastic.adapt_batch(tasks)
-        tasks.validate(self.store)
         extra: Dict[str, object] = {}
-        if self.elastic is not None:
-            pre.extend(self.elastic.on_stage_start(
-                self.store, self.replicas, self.backend))
-            if self._stealer_ok:
-                extra["stealer"] = self.elastic.stealer
-        ref_report: Optional[StageReport] = None
-        if self.replicator is not None:
-            ref_report = self.replicator.maybe_refresh()
-            extra["replicas"] = self.replicator.replicas
-        if ref_report is not None:
-            pre.append(ref_report)
+        with span("stage.boundary") if boundary else nullcontext():
+            if self.elastic is not None:
+                tasks = self.elastic.adapt_batch(tasks)
+            tasks.validate(self.store)
+            if self.elastic is not None:
+                pre.extend(self.elastic.on_stage_start(
+                    self.store, self.replicas, self.backend))
+                if self._stealer_ok:
+                    extra["stealer"] = self.elastic.stealer
+            ref_report: Optional[StageReport] = None
+            if self.replicator is not None:
+                ref_report = self.replicator.maybe_refresh()
+                extra["replicas"] = self.replicator.replicas
+            if ref_report is not None:
+                pre.append(ref_report)
         res = self.engine.run_stage(tasks, self.store, f, write_back=write_back,
                                     return_results=return_results, **extra)
         decision = getattr(res, "decision", None)
@@ -211,21 +219,22 @@ class Orchestrator:
             # ledger, indexed by the stage it decided
             decision.stage_index = self._report.num_stages
             self._report.record_decision(decision)
-        if self.replicator is not None:
-            # feed the demand histogram: Phase-1 meta-task counts when the
-            # engine reports them (tdorch), the batch's requested keys as
-            # the equivalent fallback for engines without contention
-            # detection (same totals — refcounts sum to nnz)
-            if res.refcount:
-                self.replicator.observe(res.refcount)
-            else:
-                self.replicator.observe_keys(tasks.read_indices)
-        if self.elastic is not None:
-            self.elastic.observe(tasks)
-            self.elastic.after_stage(tasks, self.store)
-            if self._stealer_ok:
-                for src, dst in self.elastic.stealer.drain():
-                    self._report.record_steals(src, dst)
+        with span("stage.boundary") if boundary else nullcontext():
+            if self.replicator is not None:
+                # feed the demand histogram: Phase-1 meta-task counts when the
+                # engine reports them (tdorch), the batch's requested keys as
+                # the equivalent fallback for engines without contention
+                # detection (same totals — refcounts sum to nnz)
+                if res.refcount:
+                    self.replicator.observe(res.refcount)
+                else:
+                    self.replicator.observe_keys(tasks.read_indices)
+            if self.elastic is not None:
+                self.elastic.observe(tasks)
+                self.elastic.after_stage(tasks, self.store)
+                if self._stealer_ok:
+                    for src, dst in self.elastic.stealer.drain():
+                        self._report.record_steals(src, dst)
         if pre:
             # boundary work (recovery, migration, replica refresh) belongs
             # to this stage's bill, each as its own phase — phase_totals()

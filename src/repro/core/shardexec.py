@@ -55,6 +55,7 @@ from . import execution
 # (one shared definition, so the two can never disagree on bucket shapes)
 from .backend import _bucket_rows as _bucket
 from .datastore import stable_bucket_slots
+from .spans import span
 from .jaxexec import (UNTRACEABLE, _as_update_rows, _segment_combine,
                       bucket_routing, detect_contention, gather_from_buckets,
                       scatter_to_buckets)
@@ -129,11 +130,12 @@ class ShardStageStats(NamedTuple):
 # ---------------------------------------------------------------------------
 # device residency (slabs per shard + replicated hot rows)
 # ---------------------------------------------------------------------------
-def _slabs_for(store, mesh: Mesh, np_dtype) -> "jnp.ndarray":
+def _slabs_for(store, mesh: Mesh, np_dtype, backend) -> "jnp.ndarray":
     """The sharded residency: a (P, K_max, w) array placed so each mesh
     shard materializes exactly the chunk rows it homes (padding rows are
     zeros nobody addresses). Cached on the store keyed by dtype and pinned
-    to `store.version` — any host mutation invalidates it."""
+    to `store.version` — any host mutation invalidates it; a miss counts
+    its upload in `backend.transfer_bytes`."""
     lay = store.shard_layout()
     cache = store.__dict__.setdefault("_spmd_values", {})
     ent = cache.get(str(np_dtype))
@@ -143,7 +145,9 @@ def _slabs_for(store, mesh: Mesh, np_dtype) -> "jnp.ndarray":
                     dtype=np_dtype)
     live = lay.slab_keys < store.num_keys
     host[live] = store.values[lay.slab_keys[live]].astype(np_dtype)
-    dev = jax.device_put(host, NamedSharding(mesh, PS(AXIS)))
+    with span("backend.upload", bytes=host.nbytes):
+        dev = jax.device_put(host, NamedSharding(mesh, PS(AXIS)))
+    backend.transfer_bytes += host.nbytes
     cache[str(np_dtype)] = (store.version, dev)
     return dev
 
@@ -153,13 +157,14 @@ def _pin_slabs(store, np_dtype, dev) -> None:
         store.version, dev)
 
 
-def _replica_arrays(store, replicas, np_dtype):
+def _replica_arrays(store, replicas, np_dtype, backend):
     """Device-side replica residency: (rep_ids (H,), lookup_ext (K+1,),
     rep_slab (H, w)) with H pow2-padded (sentinel id = num_keys), or
     (None, None, None) when nothing is fully replicated. Only chunks held by
     EVERY machine join the slab (a partial holders bitmap falls back to the
     owner fetch — values are identical either way). Cached per directory
-    object + store version."""
+    object + store version; a miss counts its upload in
+    `backend.transfer_bytes`."""
     if replicas is None or replicas.hot_ids.size == 0:
         return None, None, None
     full = replicas.holders.all(axis=1)
@@ -180,6 +185,7 @@ def _replica_arrays(store, replicas, np_dtype):
     rep_slab = np.zeros((H, store.value_width), dtype=np_dtype)
     rep_slab[:ids.size] = store.values[ids].astype(np_dtype)
     out = (jnp.asarray(rep_ids), jnp.asarray(lookup), jnp.asarray(rep_slab))
+    backend.transfer_bytes += rep_ids.nbytes + lookup.nbytes + rep_slab.nbytes
     cache[str(np_dtype)] = (store.version, sig, out)
     return out
 
@@ -259,107 +265,115 @@ def build_stage_program(mesh, *, f, fwd_mask: bool, ragged: bool,
         me = lax.axis_index(AXIS).astype(jnp.int32)
 
         # ---- Phase 1: contention detection (histogram + psum) -------------
-        if ragged:
-            prow_l, pcol_l, mask_l = prow[0], pcol[0], mask[0]
-            active = pkey >= 0
-        else:
-            active = valid & (pkey >= 0)
-        sent_key = jnp.where(active, pkey, K)
-        gcounts = detect_contention(sent_key, K + 1, AXIS)[:K]
-        owned = owner_ext[:K] == me
-        owned_demand = jnp.sum(jnp.where(owned, gcounts, 0))
+        with jax.named_scope("phase1_histogram"):
+            if ragged:
+                prow_l, pcol_l, mask_l = prow[0], pcol[0], mask[0]
+                active = pkey >= 0
+            else:
+                active = valid & (pkey >= 0)
+            sent_key = jnp.where(active, pkey, K)
+            gcounts = detect_contention(sent_key, K + 1, AXIS)[:K]
+            owned = owner_ext[:K] == me
+            owned_demand = jnp.sum(jnp.where(owned, gcounts, 0))
 
         # ---- Phase 2: push-pull co-location (replica-local or a2a fetch) --
-        if H > 0:
-            rep_slot = rep_lookup_ext[sent_key]
-            rep_hit = active & (rep_slot >= 0)
-        else:
-            rep_hit = jnp.zeros_like(active)
-        need = active & ~rep_hit
-        dest = jnp.where(need, owner_ext[sent_key], P).astype(jnp.int32)
-        routing = bucket_routing(dest, P, Np, active=need)
-        req = scatter_to_buckets(
-            slot_ext[sent_key][:, None].astype(jnp.int32), routing, P, Np,
-            fill=-1)
-        recv = _a2a(req)[..., 0].reshape(P * Np)
-        r_ok = recv >= 0
-        reply = jnp.where(r_ok[:, None],
-                          slab[jnp.clip(recv, 0, K_max - 1)],
-                          jnp.zeros((), dt)).reshape(P, Np, w)
-        fetched = gather_from_buckets(_a2a(reply), routing, Np)
-        if H > 0:
-            fetched = jnp.where(rep_hit[:, None],
-                                rep_slab[jnp.clip(rep_slot, 0, H - 1)],
-                                fetched)
+        with jax.named_scope("phase2_fetch_a2a"):
+            if H > 0:
+                rep_slot = rep_lookup_ext[sent_key]
+                rep_hit = active & (rep_slot >= 0)
+            else:
+                rep_hit = jnp.zeros_like(active)
+            need = active & ~rep_hit
+            dest = jnp.where(need, owner_ext[sent_key], P).astype(jnp.int32)
+            routing = bucket_routing(dest, P, Np, active=need)
+            req = scatter_to_buckets(
+                slot_ext[sent_key][:, None].astype(jnp.int32), routing, P, Np,
+                fill=-1)
+            recv = _a2a(req)[..., 0].reshape(P * Np)
+            r_ok = recv >= 0
+            reply = jnp.where(r_ok[:, None],
+                              slab[jnp.clip(recv, 0, K_max - 1)],
+                              jnp.zeros((), dt)).reshape(P, Np, w)
+            fetched = gather_from_buckets(_a2a(reply), routing, Np)
+            if H > 0:
+                fetched = jnp.where(rep_hit[:, None],
+                                    rep_slab[jnp.clip(rep_slot, 0, H - 1)],
+                                    fetched)
 
         # ---- Phase 3: local execution -------------------------------------
-        if ragged:
-            gathered = jnp.zeros((T, A, w), dt).at[prow_l, pcol_l].set(
-                jnp.where(active[:, None], fetched, 0), mode="drop")
-            out = f(ctx, gathered, mask_l) if fwd_mask else f(ctx, gathered)
-        else:
-            gathered = jnp.where(active[:, None], fetched, jnp.zeros((), dt))
-            out = f(ctx, gathered, active) if fwd_mask else f(ctx, gathered)
-        out = dict(out) if out is not None else {}
+        with jax.named_scope("phase3_gather_lambda"):
+            if ragged:
+                gathered = jnp.zeros((T, A, w), dt).at[prow_l, pcol_l].set(
+                    jnp.where(active[:, None], fetched, 0), mode="drop")
+                out = f(ctx, gathered, mask_l) if fwd_mask else f(ctx, gathered)
+            else:
+                gathered = jnp.where(active[:, None], fetched, jnp.zeros((), dt))
+                out = f(ctx, gathered, active) if fwd_mask else f(ctx, gathered)
+            out = dict(out) if out is not None else {}
 
-        res = out.get("result") if want_result else None
-        # absent results travel as a zero-width dummy; a 1-D (T,) result
-        # keeps its rank (the host tells the two apart by ndim, so the
-        # caller-visible shape matches the oracle exactly)
-        res = jnp.zeros((T, 0), dt) if res is None else jnp.asarray(res)
-        upd_raw = out.get("update")
+            res = out.get("result") if want_result else None
+            # absent results travel as a zero-width dummy; a 1-D (T,) result
+            # keeps its rank (the host tells the two apart by ndim, so the
+            # caller-visible shape matches the oracle exactly)
+            res = jnp.zeros((T, 0), dt) if res is None else jnp.asarray(res)
+            upd_raw = out.get("update")
 
         # ---- Phase 4: local ⊗-combine, a2a to owners, owner-side ⊙ --------
         n_comb_sent = n_comb_recv = jnp.zeros((), jnp.int32)
         writer = valid & (wk >= 0)
         if combine and upd_raw is not None:
-            u = _as_update_rows(upd_raw, T, dt)
-            uw = u.shape[1]
-            wkey = jnp.where(writer, wk, K)
-            ukeys = jnp.unique(wkey, size=T, fill_value=K)
-            seg = jnp.where(writer,
-                            jnp.searchsorted(ukeys, wkey).astype(jnp.int32),
-                            T)
-            combined, pay_o, pay_r = _local_combine(
-                u, seg, T, merge_name, order, grow)
-            uactive = ukeys < K
-            dest2 = jnp.where(uactive, owner_ext[ukeys], P).astype(jnp.int32)
-            routing2 = bucket_routing(dest2, P, T, active=uactive)
-            r_rows = _a2a(scatter_to_buckets(combined, routing2, P, T))
-            r_slot = _a2a(scatter_to_buckets(
-                slot_ext[ukeys][:, None].astype(jnp.int32), routing2, P, T,
-                fill=-1))[..., 0].reshape(P * T)
-            r_ord = _a2a(scatter_to_buckets(
-                pay_o[:, None], routing2, P, T,
-                fill=_IMAX))[..., 0].reshape(P * T)
-            r_row = _a2a(scatter_to_buckets(
-                pay_r[:, None], routing2, P, T,
-                fill=_IMAX))[..., 0].reshape(P * T)
-            r_live = r_slot >= 0
-            seg2 = jnp.where(r_live, r_slot, K_max)
-            comb2, _, _ = _local_combine(r_rows.reshape(P * T, uw), seg2,
-                                         K_max, merge_name, r_ord, r_row)
-            touched = jnp.zeros(K_max, jnp.int32).at[seg2].add(
-                1, mode="drop") > 0
-            new_slab = _apply_to_slab(slab, comb2, touched, merge_name)
-            n_comb_sent = jnp.sum(uactive.astype(jnp.int32))
-            n_comb_recv = jnp.sum(r_live.astype(jnp.int32))
+            with jax.named_scope("phase4_combine"):
+                u = _as_update_rows(upd_raw, T, dt)
+                uw = u.shape[1]
+                wkey = jnp.where(writer, wk, K)
+                ukeys = jnp.unique(wkey, size=T, fill_value=K)
+                seg = jnp.where(
+                    writer, jnp.searchsorted(ukeys, wkey).astype(jnp.int32),
+                    T)
+                combined, pay_o, pay_r = _local_combine(
+                    u, seg, T, merge_name, order, grow)
+            with jax.named_scope("phase4_a2a"):
+                uactive = ukeys < K
+                dest2 = jnp.where(uactive, owner_ext[ukeys],
+                                  P).astype(jnp.int32)
+                routing2 = bucket_routing(dest2, P, T, active=uactive)
+                r_rows = _a2a(scatter_to_buckets(combined, routing2, P, T))
+                r_slot = _a2a(scatter_to_buckets(
+                    slot_ext[ukeys][:, None].astype(jnp.int32), routing2, P,
+                    T, fill=-1))[..., 0].reshape(P * T)
+                r_ord = _a2a(scatter_to_buckets(
+                    pay_o[:, None], routing2, P, T,
+                    fill=_IMAX))[..., 0].reshape(P * T)
+                r_row = _a2a(scatter_to_buckets(
+                    pay_r[:, None], routing2, P, T,
+                    fill=_IMAX))[..., 0].reshape(P * T)
+            with jax.named_scope("phase4_apply"):
+                r_live = r_slot >= 0
+                seg2 = jnp.where(r_live, r_slot, K_max)
+                comb2, _, _ = _local_combine(r_rows.reshape(P * T, uw), seg2,
+                                             K_max, merge_name, r_ord, r_row)
+                touched = jnp.zeros(K_max, jnp.int32).at[seg2].add(
+                    1, mode="drop") > 0
+                new_slab = _apply_to_slab(slab, comb2, touched, merge_name)
+                n_comb_sent = jnp.sum(uactive.astype(jnp.int32))
+                n_comb_recv = jnp.sum(r_live.astype(jnp.int32))
         else:
             new_slab = slab
 
         # ---- replica write-through: owners broadcast post-apply rows ------
-        if H > 0 and combine and upd_raw is not None:
-            rep_live = rep_ids < K
-            rep_local = jnp.clip(slot_ext[rep_ids], 0, K_max - 1)
-            mine = rep_live & (owner_ext[rep_ids] == me)
-            rep_touch = mine & touched[rep_local]
-            contrib = jnp.where(rep_touch[:, None], new_slab[rep_local],
-                                jnp.zeros((), dt))
-            tmask = lax.psum(rep_touch.astype(jnp.int32), AXIS) > 0
-            rep_new = jnp.where(tmask[:, None], lax.psum(contrib, AXIS),
-                                rep_slab)
-        else:
-            rep_new = rep_slab
+        with jax.named_scope("phase4_apply"):
+            if H > 0 and combine and upd_raw is not None:
+                rep_live = rep_ids < K
+                rep_local = jnp.clip(slot_ext[rep_ids], 0, K_max - 1)
+                mine = rep_live & (owner_ext[rep_ids] == me)
+                rep_touch = mine & touched[rep_local]
+                contrib = jnp.where(rep_touch[:, None], new_slab[rep_local],
+                                    jnp.zeros((), dt))
+                tmask = lax.psum(rep_touch.astype(jnp.int32), AXIS) > 0
+                rep_new = jnp.where(tmask[:, None], lax.psum(contrib, AXIS),
+                                    rep_slab)
+            else:
+                rep_new = rep_slab
 
         if upd_raw is not None and want_update:
             upd = _as_update_rows(upd_raw, T, dt)
@@ -423,59 +437,60 @@ def run_sharded_stage(backend, tasks, store, f, merge,
     lay = store.shard_layout()
     np_dtype = backend._np_dtype
     n = tasks.n
-    site = tasks.origin if exec_site is None else exec_site
-    pl = place_tasks(site, P)
-    T = pl.T_cap
+    with span("backend.prepare"):
+        site = tasks.origin if exec_site is None else exec_site
+        pl = place_tasks(site, P)
+        T = pl.T_cap
 
-    ctx_np = np.asarray(tasks.contexts).astype(np_dtype, copy=False)
-    # rank-preserving: a 1-D contexts array (TaskBatch supports it) must
-    # reach the lambda as 1-D per shard, exactly as the oracle passes it
-    ctx = np.zeros((P, T) + ctx_np.shape[1:], dtype=np_dtype)
-    ctx[pl.shard, pl.slot] = ctx_np
-    valid = np.zeros((P, T), dtype=bool)
-    valid[pl.shard, pl.slot] = True
-    wk = np.full((P, T), -1, dtype=np.int32)
-    wk[pl.shard, pl.slot] = tasks.write_keys
-    order = np.zeros((P, T), dtype=np.int32)
-    order[pl.shard, pl.slot] = np.clip(tasks.priority, -2**31, 2**31 - 1)
-    grow = np.full((P, T), n, dtype=np.int32)
-    grow[pl.shard, pl.slot] = np.arange(n, dtype=np.int32)
+        ctx_np = np.asarray(tasks.contexts).astype(np_dtype, copy=False)
+        # rank-preserving: a 1-D contexts array (TaskBatch supports it) must
+        # reach the lambda as 1-D per shard, exactly as the oracle passes it
+        ctx = np.zeros((P, T) + ctx_np.shape[1:], dtype=np_dtype)
+        ctx[pl.shard, pl.slot] = ctx_np
+        valid = np.zeros((P, T), dtype=bool)
+        valid[pl.shard, pl.slot] = True
+        wk = np.full((P, T), -1, dtype=np.int32)
+        wk[pl.shard, pl.slot] = tasks.write_keys
+        order = np.zeros((P, T), dtype=np.int32)
+        order[pl.shard, pl.slot] = np.clip(tasks.priority, -2**31, 2**31 - 1)
+        grow = np.full((P, T), n, dtype=np.int32)
+        grow[pl.shard, pl.slot] = np.arange(n, dtype=np.int32)
 
-    ragged = tasks.max_arity > 1
-    A = int(tasks.max_arity) if ragged else 1
-    if ragged:
-        pair_shard = pl.shard[tasks.pair_task]
-        pair_col = np.arange(tasks.nnz, dtype=np.int64) \
-            - tasks.read_indptr[:-1][tasks.pair_task]
-        pslot, pcounts = stable_bucket_slots(pair_shard, P)
-        Np = _bucket(int(pcounts.max(initial=1)))
-        pkey = np.full((P, Np), -1, dtype=np.int32)
-        pkey[pair_shard, pslot] = tasks.read_indices
-        prow = np.full((P, Np), T, dtype=np.int32)
-        prow[pair_shard, pslot] = pl.slot[tasks.pair_task]
-        pcol = np.zeros((P, Np), dtype=np.int32)
-        pcol[pair_shard, pslot] = pair_col
-        mask = np.zeros((P, T, A), dtype=bool)
-        mask[pair_shard, pl.slot[tasks.pair_task], pair_col] = True
-    else:
-        Np = T
-        pkey = np.full((P, T), -1, dtype=np.int32)
-        pkey[pl.shard, pl.slot] = tasks.read_keys
-        prow = pcol = np.zeros((P, 1), dtype=np.int32)
-        mask = np.zeros((P, 1, 1), dtype=bool)
+        ragged = tasks.max_arity > 1
+        A = int(tasks.max_arity) if ragged else 1
+        if ragged:
+            pair_shard = pl.shard[tasks.pair_task]
+            pair_col = np.arange(tasks.nnz, dtype=np.int64) \
+                - tasks.read_indptr[:-1][tasks.pair_task]
+            pslot, pcounts = stable_bucket_slots(pair_shard, P)
+            Np = _bucket(int(pcounts.max(initial=1)))
+            pkey = np.full((P, Np), -1, dtype=np.int32)
+            pkey[pair_shard, pslot] = tasks.read_indices
+            prow = np.full((P, Np), T, dtype=np.int32)
+            prow[pair_shard, pslot] = pl.slot[tasks.pair_task]
+            pcol = np.zeros((P, Np), dtype=np.int32)
+            pcol[pair_shard, pslot] = pair_col
+            mask = np.zeros((P, T, A), dtype=bool)
+            mask[pair_shard, pl.slot[tasks.pair_task], pair_col] = True
+        else:
+            Np = T
+            pkey = np.full((P, T), -1, dtype=np.int32)
+            pkey[pl.shard, pl.slot] = tasks.read_keys
+            prow = pcol = np.zeros((P, 1), dtype=np.int32)
+            mask = np.zeros((P, 1, 1), dtype=bool)
 
-    K = store.num_keys
-    owner_ext = np.concatenate(
-        [lay.owner.astype(np.int32), np.int32([P])])
-    slot_ext = np.concatenate(
-        [lay.local_slot.astype(np.int32), np.int32([lay.slab_rows])])
-    rep_ids, rep_lookup_ext, rep_slab = _replica_arrays(
-        store, replicas, np_dtype)
-    H = 0 if rep_ids is None else int(rep_ids.shape[0])
-    if H == 0:
-        rep_ids = jnp.zeros(1, jnp.int32)
-        rep_lookup_ext = jnp.zeros(1, jnp.int32)
-        rep_slab = jnp.zeros((1, store.value_width), np_dtype)
+        K = store.num_keys
+        owner_ext = np.concatenate(
+            [lay.owner.astype(np.int32), np.int32([P])])
+        slot_ext = np.concatenate(
+            [lay.local_slot.astype(np.int32), np.int32([lay.slab_rows])])
+        rep_ids, rep_lookup_ext, rep_slab = _replica_arrays(
+            store, replicas, np_dtype, backend)
+        H = 0 if rep_ids is None else int(rep_ids.shape[0])
+        if H == 0:
+            rep_ids = jnp.zeros(1, jnp.int32)
+            rep_lookup_ext = jnp.zeros(1, jnp.int32)
+            rep_slab = jnp.zeros((1, store.value_width), np_dtype)
 
     fwd = execution._accepts_mask(f)
     sig = (id(f), fwd, ragged, merge.name if merge is not None else None,
@@ -490,18 +505,25 @@ def run_sharded_stage(backend, tasks, store, f, merge,
             want_result=want_result, P=P, K=K, K_max=lay.slab_rows, T=T,
             Np=Np, A=A, H=H, w=store.value_width, np_dtype=np_dtype)
 
-    slabs = _slabs_for(store, mesh, np_dtype)
+    slabs = _slabs_for(store, mesh, np_dtype, backend)
+    # the program uploads its host operands: the per-shard blocks once, the
+    # replicated key maps to every shard
+    host_ops = (ctx, valid, wk, order, grow, pkey, prow, pcol, mask)
+    backend.transfer_bytes += (sum(a.nbytes for a in host_ops)
+                               + P * (owner_ext.nbytes + slot_ext.nbytes))
     try:
-        res_d, upd_d, new_slabs, rep_new, stats_d = prog(
-            slabs, ctx, valid, wk, order, grow, pkey, prow, pcol, mask,
-            owner_ext, slot_ext, rep_ids, rep_lookup_ext, rep_slab)
+        with span("backend.dispatch"):
+            res_d, upd_d, new_slabs, rep_new, stats_d = prog(
+                slabs, *host_ops, owner_ext, slot_ext, rep_ids,
+                rep_lookup_ext, rep_slab)
     except UNTRACEABLE as e:
         # only an untraceable lambda is fallback-eligible (mirrors the jax
         # backend, whose try covers exactly the jitted stage call)
         raise ShardStageError(
             f"sharded stage lambda is not traceable: {e}") from e
 
-    stats_np = np.asarray(stats_d)
+    stats_np = backend._fetch(stats_d)
+    backend.host_syncs += 1
     stats = ShardStageStats(*(stats_np[:, i].astype(np.int64)
                               for i in range(stats_np.shape[1])))
 
@@ -514,17 +536,16 @@ def run_sharded_stage(backend, tasks, store, f, merge,
     # res_d is (P, T) for a 1-D lambda result, (P, T, rw) otherwise
     # (rw == 0 means the lambda returned no result at all)
     if want_result and (res_d.ndim == 2 or res_d.shape[-1] > 0):
-        out["result"] = np.asarray(res_d)[pl.shard, pl.slot]
+        out["result"] = backend._fetch(res_d)[pl.shard, pl.slot]
         backend.host_syncs += 1
     if want_update and upd_d.shape[-1] > 0:
-        out["update"] = np.asarray(upd_d)[pl.shard, pl.slot]
+        out["update"] = backend._fetch(upd_d)[pl.shard, pl.slot]
         backend.host_syncs += 1
     return out
 
 
-def gather_slab_rows(store, new_slabs, keys: np.ndarray) -> np.ndarray:
-    """Read the post-apply rows for `keys` back out of the sharded slabs
-    (one cross-device gather + host transfer)."""
+def gather_slab_rows(store, new_slabs, keys: np.ndarray):
+    """The post-apply rows for `keys` gathered out of the sharded slabs
+    (one cross-device gather; the caller fetches them to the host)."""
     lay = store.shard_layout()
-    rows = new_slabs[lay.owner[keys], lay.local_slot[keys]]
-    return np.asarray(rows)
+    return new_slabs[lay.owner[keys], lay.local_slot[keys]]

@@ -38,6 +38,7 @@ from ..core.backend import make_backend
 from ..core.cost import CostAccumulator, StageReport
 from ..core.mergeops import get_merge_op
 from ..core.replication import charge_write_through
+from ..core.spans import span, spanned
 from .partition import OrchestratedGraph
 from .session import VALUE_WORDS, TreeCharger, _expand_csr, session_for
 from .vertex_subset import DistVertexSubset
@@ -94,6 +95,7 @@ class EdgeMapStats:
     report: Optional[StageReport] = None
 
 
+@spanned("edgemap")
 def dist_edge_map(
     og: OrchestratedGraph,
     U: DistVertexSubset,
@@ -144,135 +146,142 @@ def dist_edge_map(
         replicas = None
 
     # ---- mode selection (§5.1): sparse for small frontiers ---------------
-    # A session armed with engine="auto" (GraphSession.mode_policy) replaces
-    # the static Ligra direction threshold with the cost model itself: both
-    # modes' propagation bills are charged against scratch accumulators
-    # (exact — the downstream edge-compute and write-back costs are
-    # mode-independent) and the argmin wins under the BSP objective.
-    policy = getattr(sess, "mode_policy", None)
-    decision = None
-    if force_mode is not None:
-        mode = force_mode
-    elif policy is not None and account and not per_edge_comm:
-        estimates = _estimate_mode_costs(og, sess, idx, replicas, dedup)
-        decision = policy.choose(estimates, kind="edge_map_mode")
-        mode = decision.choice
-    else:
-        mode = "sparse" if (sum_deg + idx.size) < threshold_frac * (g.m + g.n) else "dense"
+    with span("edgemap.gather"):
+        # A session armed with engine="auto" (GraphSession.mode_policy) replaces
+        # the static Ligra direction threshold with the cost model itself: both
+        # modes' propagation bills are charged against scratch accumulators
+        # (exact — the downstream edge-compute and write-back costs are
+        # mode-independent) and the argmin wins under the BSP objective.
+        policy = getattr(sess, "mode_policy", None)
+        decision = None
+        if force_mode is not None:
+            mode = force_mode
+        elif policy is not None and account and not per_edge_comm:
+            estimates = _estimate_mode_costs(og, sess, idx, replicas, dedup)
+            decision = policy.choose(estimates, kind="edge_map_mode")
+            mode = decision.choice
+        else:
+            mode = "sparse" if (sum_deg + idx.size) < threshold_frac * (g.m + g.n) else "dense"
 
-    # ---- gather active edges ----------------------------------------------
-    if mode == "sparse":
-        flat, _ = _expand_csr(og.out_indptr, idx)
-        edge_ids = og.out_edges[flat]
-    else:
-        edge_ids = np.flatnonzero(U.mask[g.src])
-    s, d = g.src[edge_ids], g.dst[edge_ids]
-    w = g.weights[edge_ids] if g.weights is not None else np.ones(edge_ids.size)
+        # ---- gather active edges ------------------------------------------
+        if mode == "sparse":
+            flat, _ = _expand_csr(og.out_indptr, idx)
+            edge_ids = og.out_edges[flat]
+        else:
+            edge_ids = np.flatnonzero(U.mask[g.src])
+        s, d = g.src[edge_ids], g.dst[edge_ids]
+        w = g.weights[edge_ids] if g.weights is not None else np.ones(edge_ids.size)
 
-    if filter_dst is not None and edge_ids.size:
-        keep = filter_dst(d)
-        edge_ids, s, d, w = edge_ids[keep], s[keep], d[keep], w[keep]
+        if filter_dst is not None and edge_ids.size:
+            keep = filter_dst(d)
+            edge_ids, s, d, w = edge_ids[keep], s[keep], d[keep], w[keep]
 
     cost = CostAccumulator(og.P) if account else None
     if cost is not None:
         cost.begin(f"edgemap_{mode}")
 
     # ---- cost: source-value propagation ------------------------------------
-    if cost is not None and per_edge_comm and edge_ids.size:
-        # Ligra-Dist/ghost-node baseline (Table 3): every active edge does
-        # its own remote read of dist[src] and remote write to dist[dst] —
-        # no meta-task aggregation, no trees, no per-machine dedup. Hot
-        # vertices' home machines absorb per-edge message storms.
-        em = og.edge_machine[edge_ids]
-        cost.send(og.vertex_home[s], em, VALUE_WORDS)
-        cost.work(em, 1.0 if fast_local else 3.0)
-        cost.send(em, og.vertex_home[d], VALUE_WORDS)
-        cost.work(og.vertex_home[d], 1.0)
-        cost.tick(2)
-    elif cost is not None and idx.size:
-        # replicated sources: every machine holding their out-edges already
-        # has the value — a machine-local read, no tree/broadcast traffic
-        live = idx
-        if replicas is not None and mode in ("sparse", "dense"):
-            # a vertex counts as replicated only when EVERY machine holds it
-            # (conservative under a partial holders bitmap: any gap falls
-            # back to the full tree broadcast)
-            slot = replicas.lookup[idx]
-            hot = slot >= 0
-            hot[hot] = replicas.holders[slot[hot]].all(axis=1)
-            if hot.any() and (dedup or mode == "sparse"):
-                flat_h, _ = _expand_csr(og.src_grp_indptr, idx[hot])
-                cost.local(og.src_grp_machines[flat_h], VALUE_WORDS)
-                live = idx[~hot]
-        if mode == "sparse":
-            h = (sess.src_charger.charge(cost, live, VALUE_WORDS, upward=False)
-                 if live.size else 0)
-            cost.tick(max(h, 1))
-        else:
-            if dedup:
-                # T1 destination-aware broadcast: value -> only machines
-                # holding that vertex's out-edges, one copy each
-                if live.size:
-                    sess.src_charger.direct_broadcast(cost, live, VALUE_WORDS)
+    with span("edgemap.propagate"):
+        if cost is not None and per_edge_comm and edge_ids.size:
+            # Ligra-Dist/ghost-node baseline (Table 3): every active edge does
+            # its own remote read of dist[src] and remote write to dist[dst] —
+            # no meta-task aggregation, no trees, no per-machine dedup. Hot
+            # vertices' home machines absorb per-edge message storms.
+            em = og.edge_machine[edge_ids]
+            cost.send(og.vertex_home[s], em, VALUE_WORDS)
+            cost.work(em, 1.0 if fast_local else 3.0)
+            cost.send(em, og.vertex_home[d], VALUE_WORDS)
+            cost.work(og.vertex_home[d], 1.0)
+            cost.tick(2)
+        elif cost is not None and idx.size:
+            # replicated sources: every machine holding their out-edges already
+            # has the value — a machine-local read, no tree/broadcast traffic
+            live = idx
+            if replicas is not None and mode in ("sparse", "dense"):
+                # a vertex counts as replicated only when EVERY machine holds it
+                # (conservative under a partial holders bitmap: any gap falls
+                # back to the full tree broadcast)
+                slot = replicas.lookup[idx]
+                hot = slot >= 0
+                hot[hot] = replicas.holders[slot[hot]].all(axis=1)
+                if hot.any() and (dedup or mode == "sparse"):
+                    flat_h, _ = _expand_csr(og.src_grp_indptr, idx[hot])
+                    cost.local(og.src_grp_machines[flat_h], VALUE_WORDS)
+                    live = idx[~hot]
+            if mode == "sparse":
+                h = (sess.src_charger.charge(cost, live, VALUE_WORDS, upward=False)
+                     if live.size else 0)
+                cost.tick(max(h, 1))
             else:
-                # naive dense: broadcast every active value to all machines
-                allm = np.arange(og.P, dtype=np.int64)
-                for mch in allm:
-                    cost.send(og.vertex_home[idx], np.full(idx.size, mch),
-                              VALUE_WORDS)
-            cost.tick(1)
+                if dedup:
+                    # T1 destination-aware broadcast: value -> only machines
+                    # holding that vertex's out-edges, one copy each
+                    if live.size:
+                        sess.src_charger.direct_broadcast(cost, live, VALUE_WORDS)
+                else:
+                    # naive dense: broadcast every active value to all machines
+                    allm = np.arange(og.P, dtype=np.int64)
+                    for mch in allm:
+                        cost.send(og.vertex_home[idx], np.full(idx.size, mch),
+                                  VALUE_WORDS)
+                cost.tick(1)
 
     # ---- local compute ------------------------------------------------------
     if edge_ids.size:
-        vals = np.asarray(f(s, d, w), dtype=np.float64)
-        # T2 ablation (fast_local=False): charge the generic CAS-loop
-        # constant instead of the work-efficient segmented combine — the
-        # 2–5.7× band Table 4 measures. Numerics are unaffected.
-        if cost is not None:
-            cost.work(og.edge_machine[edge_ids], 1.0 if fast_local else 3.0)
+        with span("edgemap.f"):
+            vals = np.asarray(f(s, d, w), dtype=np.float64)
+            # T2 ablation (fast_local=False): charge the generic CAS-loop
+            # constant instead of the work-efficient segmented combine — the
+            # 2–5.7× band Table 4 measures. Numerics are unaffected.
+            if cost is not None:
+                cost.work(og.edge_machine[edge_ids],
+                          1.0 if fast_local else 3.0)
         # per-destination ⊗-combine through the session's execution backend
         # (numpy oracle, or the jitted segment scatter of core/jaxexec.py)
-        uniq_d, combined = bk.combine_by_key(vals[:, None], d, og.n, merge,
-                                             edge_ids)
+        with span("edgemap.combine"):
+            uniq_d, combined = bk.combine_by_key(vals[:, None], d, og.n,
+                                                 merge, edge_ids)
     else:
         uniq_d = np.empty(0, dtype=np.int64)
         combined = np.empty((0, 1))
 
     # ---- cost: write-back combine up the destination trees -----------------
-    if cost is not None and edge_ids.size and not per_edge_comm:
-        pair = d * np.int64(og.P) + og.edge_machine[edge_ids]
-        upair = np.unique(pair)
-        uv = (upair // og.P).astype(np.int64)
-        um = (upair % og.P).astype(np.int64)
-        if dedup:
-            # group by vertex: CSR over (uv, um), tree-combine to vertex home
-            # (per-round charger: the touched (vertex, machine) set depends
-            # on this round's active edges)
-            indptr = np.zeros(og.n + 1, dtype=np.int64)
-            np.add.at(indptr, uv + 1, 1)
-            np.cumsum(indptr, out=indptr)
-            vset = np.unique(uv)
-            dst_charger = TreeCharger(og.vertex_home, indptr, um, og.C)
-            h = dst_charger.charge(cost, vset, VALUE_WORDS, upward=True)
-            cost.tick(max(h, 1))
-        else:
-            # no en-route combining: every machine writes straight to home
-            cost.send(um, og.vertex_home[uv], VALUE_WORDS)
-            cost.tick(1)
-        cost.work(og.vertex_home[uniq_d], 1.0)
+    with span("edgemap.writeback_cost"):
+        if cost is not None and edge_ids.size and not per_edge_comm:
+            pair = d * np.int64(og.P) + og.edge_machine[edge_ids]
+            upair = np.unique(pair)
+            uv = (upair // og.P).astype(np.int64)
+            um = (upair % og.P).astype(np.int64)
+            if dedup:
+                # group by vertex: CSR over (uv, um), tree-combine to vertex home
+                # (per-round charger: the touched (vertex, machine) set depends
+                # on this round's active edges)
+                indptr = np.zeros(og.n + 1, dtype=np.int64)
+                np.add.at(indptr, uv + 1, 1)
+                np.cumsum(indptr, out=indptr)
+                vset = np.unique(uv)
+                dst_charger = TreeCharger(og.vertex_home, indptr, um, og.C)
+                h = dst_charger.charge(cost, vset, VALUE_WORDS, upward=True)
+                cost.tick(max(h, 1))
+            else:
+                # no en-route combining: every machine writes straight to home
+                cost.send(um, og.vertex_home[uv], VALUE_WORDS)
+                cost.tick(1)
+            cost.work(og.vertex_home[uniq_d], 1.0)
 
     # ---- apply + next frontier ---------------------------------------------
-    if uniq_d.size:
-        changed = np.asarray(write_back(uniq_d, combined[:, 0]), dtype=bool)
-        nxt = DistVertexSubset(og.n, indices=uniq_d[changed])
-        # replicated destinations whose value actually changed: home
-        # write-through-propagates the new value to every holder, keeping
-        # replicas fresh (unchanged homes need no propagation)
-        if cost is not None and replicas is not None and not per_edge_comm:
-            charge_write_through(cost, og.vertex_home, replicas,
-                                 uniq_d[changed], VALUE_WORDS)
-    else:
-        nxt = DistVertexSubset.empty(og.n)
+    with span("edgemap.apply"):
+        if uniq_d.size:
+            changed = np.asarray(write_back(uniq_d, combined[:, 0]), dtype=bool)
+            nxt = DistVertexSubset(og.n, indices=uniq_d[changed])
+            # replicated destinations whose value actually changed: home
+            # write-through-propagates the new value to every holder, keeping
+            # replicas fresh (unchanged homes need no propagation)
+            if cost is not None and replicas is not None and not per_edge_comm:
+                charge_write_through(cost, og.vertex_home, replicas,
+                                     uniq_d[changed], VALUE_WORDS)
+        else:
+            nxt = DistVertexSubset.empty(og.n)
 
     if rep is not None:
         # demand feed: a vertex is "requested" once per machine that needs

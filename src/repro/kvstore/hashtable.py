@@ -24,6 +24,7 @@ import numpy as np
 from ..core import (CARRY, DataStore, OrchestrationResult, Orchestrator,
                     SessionReport, StagePlan, TaskBatch,
                     resolve_session_config)
+from ..core.spans import span
 from ..serve import Frontend, RequestFuture  # noqa: F401 (RequestFuture: API)
 
 
@@ -204,12 +205,14 @@ class DistributedHashTable:
         the table's replicating session for this engine (see `session`);
         `backend=` through its numpy-oracle or jitted-jax session;
         `config=` carries the whole session spec as one `SessionConfig`."""
-        tasks = self._make_batch(keys, is_read, operand, origin)
-        res: OrchestrationResult = self.session(
-            engine, replicate=replicate, backend=backend, config=config,
-            **engine_opts
-        ).run_stage(tasks, _muladd_lambda, write_back="write",
-                    return_results=True)
+        with span("kv.batch"):
+            with span("kv.make_batch"):
+                tasks = self._make_batch(keys, is_read, operand, origin)
+            res: OrchestrationResult = self.session(
+                engine, replicate=replicate, backend=backend, config=config,
+                **engine_opts
+            ).run_stage(tasks, _muladd_lambda, write_back="write",
+                        return_results=True)
         return KVResult(values=res.results, report=res.report, refcount=res.refcount)
 
     # ---- dependent read-modify-write chains --------------------------------
