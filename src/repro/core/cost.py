@@ -113,6 +113,15 @@ class CostAccumulator:
         np.add.at(ph.sent, src[remote], words[remote])
         np.add.at(ph.recv, dst[remote], words[remote])
 
+    def add_comm(self, sent: np.ndarray, recv: np.ndarray) -> None:
+        """Add per-machine sent/recv words charged earlier into another
+        accumulator (a bill that repeats round after round). The same as
+        charging those messages here when every word count is a whole number
+        below 2**53: float64 sums of such numbers do not depend on order."""
+        ph = self._require()
+        ph.sent += sent
+        ph.recv += recv
+
     def work(self, machine: np.ndarray, units) -> None:
         ph = self._require()
         machine = np.asarray(machine, dtype=np.int64).ravel()

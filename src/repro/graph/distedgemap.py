@@ -16,10 +16,13 @@ is accounted against the ingestion-time source/destination trees:
 Write-backs are ⊗-combined per (machine, destination), then climb the
 destination tree to the vertex home (§5.1 "destination trees").
 
-The source-tree machinery (per-member parent maps over the C-ary trees) is
-session state: rounds driven through a `GraphSession` reuse the session's
-precomputed `TreeCharger`; direct calls borrow the graph's cached default
-session instead of rebuilding the layout per call.
+The tree machinery (per-member parent maps over the C-ary trees) is session
+state: rounds driven through a `GraphSession` reuse the session's source
+and destination `TreeCharger`s; direct calls borrow the graph's cached
+default session instead of rebuilding the layout per call. A round whose
+active edges are all the graph's edges charges its write-back on the
+ingest-time destination trees (`GraphSession.charge_full_writeback`); any
+other round derives the (destination, machine) pairs its edges touch.
 
 Hot-vertex replication (`replicate=`, session-owned, cost-model only): the
 session's `HotChunkReplicator` learns per-round vertex demand and keeps the
@@ -248,25 +251,30 @@ def dist_edge_map(
     # ---- cost: write-back combine up the destination trees -----------------
     with span("edgemap.writeback_cost"):
         if cost is not None and edge_ids.size and not per_edge_comm:
-            pair = d * np.int64(og.P) + og.edge_machine[edge_ids]
-            upair = np.unique(pair)
-            uv = (upair // og.P).astype(np.int64)
-            um = (upair % og.P).astype(np.int64)
-            if dedup:
-                # group by vertex: CSR over (uv, um), tree-combine to vertex home
-                # (per-round charger: the touched (vertex, machine) set depends
-                # on this round's active edges)
-                indptr = np.zeros(og.n + 1, dtype=np.int64)
-                np.add.at(indptr, uv + 1, 1)
-                np.cumsum(indptr, out=indptr)
-                vset = np.unique(uv)
-                dst_charger = TreeCharger(og.vertex_home, indptr, um, og.C)
-                h = dst_charger.charge(cost, vset, VALUE_WORDS, upward=True)
-                cost.tick(max(h, 1))
+            if mode != "sparse" and edge_ids.size == g.m:
+                # a dense gather yields distinct edge ids, so m of them are
+                # every edge: the touched pairs are the ingest-time groups
+                sess.charge_full_writeback(cost, dedup)
             else:
-                # no en-route combining: every machine writes straight to home
-                cost.send(um, og.vertex_home[uv], VALUE_WORDS)
-                cost.tick(1)
+                pair = d * np.int64(og.P) + og.edge_machine[edge_ids]
+                upair = np.unique(pair)
+                uv = (upair // og.P).astype(np.int64)
+                um = (upair % og.P).astype(np.int64)
+                if dedup:
+                    # group by vertex: CSR over (uv, um), tree-combine to
+                    # vertex home (per-round charger: the touched (vertex,
+                    # machine) set depends on this round's active edges)
+                    indptr = np.zeros(og.n + 1, dtype=np.int64)
+                    np.add.at(indptr, uv + 1, 1)
+                    np.cumsum(indptr, out=indptr)
+                    vset = np.unique(uv)
+                    dst_charger = TreeCharger(og.vertex_home, indptr, um, og.C)
+                    h = dst_charger.charge(cost, vset, VALUE_WORDS, upward=True)
+                    cost.tick(max(h, 1))
+                else:
+                    # no en-route combining: every machine writes straight home
+                    cost.send(um, og.vertex_home[uv], VALUE_WORDS)
+                    cost.tick(1)
             cost.work(og.vertex_home[uniq_d], 1.0)
 
     # ---- apply + next frontier ---------------------------------------------

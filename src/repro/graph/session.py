@@ -5,12 +5,17 @@ ingestion-time topology, so the tree machinery is session state, not
 per-call state:
 
   * `TreeCharger` precomputes — once — the parent machine of every member of
-    every C-ary source tree (the heap layout over [root, m0, m1, ...] that
+    every C-ary source or destination tree (the heap layout over [root, m0, m1, ...] that
     `dist_edge_map` previously re-derived from the CSR on every round).
-  * `GraphSession` owns the chargers for one `OrchestratedGraph` and folds
-    every round's `StageReport` into one cross-round `SessionReport`
-    (per-phase words/rounds/work summed), mirroring
-    `core.session.Orchestrator` for the kv/orchestration side.
+  * `GraphSession` owns both chargers for one `OrchestratedGraph` — the
+    source trees' and the destination trees' — and folds every round's
+    `StageReport` into one cross-round `SessionReport` (per-phase
+    words/rounds/work summed), mirroring `core.session.Orchestrator` for the
+    kv/orchestration side.
+  * A round whose active edges are all the graph's edges (every PageRank
+    round) climbs every ingest-time destination tree once, so its write-back
+    bill is the same every such round: the session charges it once and adds
+    it thereafter (`charge_full_writeback`).
 
 Algorithms construct one session per run (`GraphSession(og, **opts)`) and
 call `session.edge_map(...)` per round; calling `dist_edge_map` directly
@@ -20,6 +25,7 @@ machinery without recording into it.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import List, Tuple
 
 import numpy as np
@@ -190,6 +196,10 @@ class GraphSession:
             check(og.P)
         self._report = SessionReport(og.P)
         self.stats: List = []
+        # accounted rounds whose write-back charge read the ingest-time
+        # destination trees (charge_full_writeback)
+        self.dst_tree_reuses = 0
+        self._full_writeback = {}  # dedup -> (sent, recv, rounds)
 
     # ------------------------------------------------------------------
     @property
@@ -208,6 +218,45 @@ class GraphSession:
     @property
     def num_rounds(self) -> int:
         return len(self.stats)
+
+    @functools.cached_property
+    def dst_charger(self) -> TreeCharger:
+        """The ingest-time destination trees (built on first use: only
+        rounds with every edge active read them)."""
+        og = self.og
+        return TreeCharger(og.vertex_home, og.dst_grp_indptr,
+                           og.dst_grp_machines, og.C)
+
+    def charge_full_writeback(self, cost: CostAccumulator,
+                              dedup: bool) -> None:
+        """Charge the write-back of a round whose active edges are all the
+        graph's edges. Its (destination, machine) pairs are then exactly the
+        ingest-time destination groups, so the bill — every group's tree
+        climbing to the vertex home (dedup), or every member writing
+        straight home — is the same every such round. It is charged once
+        into a scratch accumulator and added thereafter: word counts are
+        whole numbers far below 2**53, so the float64 sums come out the same
+        as charging the messages one by one."""
+        bill = self._full_writeback.get(dedup)
+        if bill is None:
+            og = self.og
+            scratch = CostAccumulator(og.P)
+            scratch.begin("writeback")
+            counts = np.diff(og.dst_grp_indptr)
+            if dedup:
+                h = self.dst_charger.charge(scratch, np.flatnonzero(counts),
+                                            VALUE_WORDS, upward=True)
+                rounds = max(h, 1)
+            else:
+                homes = np.repeat(og.vertex_home, counts)
+                scratch.send(og.dst_grp_machines, homes, VALUE_WORDS)
+                rounds = 1
+            ph = scratch.end()
+            bill = self._full_writeback[dedup] = (ph.sent, ph.recv, rounds)
+        sent, recv, rounds = bill
+        cost.add_comm(sent, recv)
+        cost.tick(rounds)
+        self.dst_tree_reuses += 1
 
     def ensure_replicator(self, spec=True):
         """Create the session's replicator on first use (for
