@@ -316,7 +316,8 @@ def spmd_phase(seed: int, chips: int, keys_per_chip: int = 1 << 20) -> None:
     per_chip = []
     for s in shards:
         m = s.index[0].start
-        rows = np.asarray(s.data)[0]
+        # a device shard is padded to the TPU's tile past the layout's rows
+        rows = np.asarray(s.data)[0, :lay.slab_rows, :YCSB_WIDTH]
         live = lay.slab_keys[m] < num_keys
         err = max_err(rows[live], ref[lay.slab_keys[m][live]])
         check(err == 0.0, f"shard {m} on {s.device} off the reference")

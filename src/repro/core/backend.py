@@ -701,6 +701,11 @@ class SpmdBackend(JaxBackend):
         self._sx = shardexec
         self._programs: dict = {}  # compiled stage per (lambda, shape sig)
         self.stage_stats: list = []
+        # the stages' all-to-all send buffers: their bytes as compiled
+        # (padded), summed over shards, and the live rows they carried
+        # (requests, replies, combined write-backs)
+        self.exchange_bytes = 0
+        self.exchange_rows = 0
 
     # -- fail-fast device-count validation ----------------------------------
     def validate_machines(self, P: int) -> None:
@@ -712,11 +717,23 @@ class SpmdBackend(JaxBackend):
         out, self.stage_stats = self.stage_stats, []
         return out
 
-    def resident(self, store):
-        """The (P, slab_rows, w) array sharded over the mesh — shard m holds
-        the chunks machine m homes (None before the first stage)."""
+    def _slabs(self, store):
         ent = store.__dict__.get("_spmd_values", {}).get(str(self._np_dtype))
         return None if ent is None else ent[1]
+
+    def resident(self, store):
+        """The (P, slab_rows, w) values sharded over the mesh — shard m holds
+        the chunks machine m homes (None before the first stage) — as a
+        `shardexec.SlabView` of the padded device slabs: for reading, not
+        for the stage path."""
+        slabs = self._slabs(store)
+        return None if slabs is None else self._sx.SlabView(
+            slabs, store.shard_layout().slab_rows, store.value_width)
+
+    def sync(self, store=None) -> None:
+        slabs = None if store is None else self._slabs(store)
+        if slabs is not None:
+            self._jax.block_until_ready(slabs)
 
     def prefetch(self, tasks, store) -> None:
         """Sharded stages materialize per-shard operands inside the stage
@@ -748,6 +765,8 @@ class SpmdBackend(JaxBackend):
             self._host_lambdas.add(id(f))
             return self._host_stage(tasks, store, f)
         self.stage_stats.append(out["stats"])
+        self.exchange_bytes += out["exchange_bytes"]
+        self.exchange_rows += out["exchange_rows"]
         host: Dict[str, Optional[np.ndarray]] = {"result": out["result"],
                                                  "update": out["update"]}
         # update_width == 0 means the lambda returned no "update" at all —
@@ -778,12 +797,10 @@ class SpmdBackend(JaxBackend):
             return
         cost.work(store.home[uniq], 1.0)
         # the owner shards already ⊙-applied to their slabs inside the
-        # stage program; the authoritative host copy catches up with one
-        # cross-shard gather of exactly the written rows
+        # stage program; the authoritative host copy catches up with the
+        # written rows, each shard reading its own
         with span("backend.writeback"):
-            rows = self._fetch(self._sx.gather_slab_rows(store, new_slabs,
-                                                         uniq))
-            self.host_syncs += 1
+            rows = self._sx.fetch_slab_rows(self, store, new_slabs, uniq)
             store.write_rows(uniq, rows.astype(store.values.dtype,
                                                copy=False))
         self._sx._pin_slabs(store, self._np_dtype, new_slabs)

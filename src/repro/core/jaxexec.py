@@ -64,9 +64,8 @@ def detect_contention(item_ids, num_items: int,
     (`contention_counts`) plus, under SPMD, one `psum` over `axis_name` —
     on TPU an all-reduce *is* the balanced aggregation tree the paper
     builds by hand, so counts ride it directly. `core/spmd.py` (MoE
-    dispatch), `core/shardexec.py` (the mesh-sharded simulator backend) and
-    `core/embedding.py` all call this same function; pass ``axis_name=None``
-    for the single-device form."""
+    dispatch) and `core/embedding.py` call this same function; pass
+    ``axis_name=None`` for the single-device form."""
     counts = contention_counts(jnp.asarray(item_ids).reshape(-1), num_items,
                                weights=weights, kernel_backend=kernel_backend)
     if axis_name is not None:

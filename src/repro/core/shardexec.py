@@ -9,22 +9,30 @@ only the `DataStore` chunks it homes (plus the session's `ReplicaSet`
 entries), holds only the tasks the cost model placed on it (`exec_site`),
 and runs the four phases locally with collective exchanges in between:
 
-  Phase 1 (contention detection): per-shard histogram of requested chunk
-    keys + one `psum` — the unified `jaxexec.detect_contention` primitive
-    (the same call the MoE dispatch path makes).
-  Phase 2 (co-location): each (task, requested-key) pair sends a request to
-    the key's owner shard via a bucketed power-of-two ragged `all_to_all`
-    (the pow2 padding from the plan scope, so drifting batch sizes share
-    compiled executables); owners reply with the chunk rows, a second
-    `all_to_all` brings them home. Pairs whose chunk is in the shard's
-    replica slab never touch the wire — they read the local copy.
+  Phase 1 (contention detection): each shard counts its active (task,
+    requested-key) pairs by the key's owner shard, and one `psum` of those
+    P bins gives every owner the demand on its chunks.
+  Phase 2 (co-location): each pair sends a request to the key's owner
+    shard via a bucketed power-of-two ragged `all_to_all` (the pow2 padding
+    from the plan scope, so drifting batch sizes share compiled
+    executables); owners reply with the chunk rows, a second `all_to_all`
+    brings them home. Pairs whose chunk is in the shard's replica slab
+    never touch the wire — they read the local copy.
   Phase 3: the stage lambda runs on each shard over its local gathered
     view — exactly the `jaxexec.run_stage_*` numerics, per shard.
   Phase 4: write-backs ⊗-combine *locally* per written key, the combined
     rows ride one more `all_to_all` to the owner shards, each owner
-    ⊙-applies to its slab, and written chunks that are replicated
-    write-through their post-apply rows to every holder (a masked `psum` —
-    the broadcast tree the hardware provides).
+    ⊗-combines what it received per slab row and scatters the result into
+    its slab with the merge's ⊙ (in place: the stage donates the slab), and
+    written chunks that are replicated write-through their post-apply rows
+    to every holder (a masked `psum` — the broadcast tree the hardware
+    provides).
+
+Every key-to-shard map the phases need (each pair's owner, slab row and
+replica slot, each writer's owner and slab row) is looked up on the host
+from `DataStore.shard_layout()` and handed to the program per shard, so no
+operand, intermediate or transfer of a warm stage scales with the table:
+only the resident slab does.
 
 The contract that keeps this big change safe (`core/backend.py`
 `SpmdBackend`): every cost-model input is still produced host-side by the
@@ -57,7 +65,7 @@ from .backend import _bucket_rows as _bucket
 from .datastore import stable_bucket_slots
 from .spans import span
 from .jaxexec import (UNTRACEABLE, _as_update_rows, _segment_combine,
-                      bucket_routing, detect_contention, gather_from_buckets,
+                      bucket_routing, gather_from_buckets,
                       scatter_to_buckets)
 
 AXIS = "shards"
@@ -130,71 +138,197 @@ class ShardStageStats(NamedTuple):
 # ---------------------------------------------------------------------------
 # device residency (slabs per shard + replicated hot rows)
 # ---------------------------------------------------------------------------
+UPLOAD_ROWS = 1 << 16  # slab rows of every shard staged per upload block
+TILE = (8, 128)  # sublanes x lanes of a TPU tile of 32-bit words
+
+
+def slab_shape(rows: int, w: int) -> tuple:
+    """(rows, words) of a shard's device slab: the layout's slab rows and
+    the record's words rounded up to a TPU tile. A TPU keeps a (1, rows,
+    words) shard row-major only when both fit its tiles; otherwise it lays
+    the shard out with one-row tiles or with the words major, and every
+    stage would relayout the whole slab, twice, to reach its rows (the
+    tiles pad a row to the lanes either way). Padding rows and lanes are
+    zeros nobody reads."""
+    return tuple(-(-n // t) * t for n, t in zip((rows, w), TILE))
+
+
+class SlabView:
+    """The resident slabs read as the (P, slab_rows, w) array they hold.
+    Indexing (integers, slices, integer or boolean arrays, one Ellipsis)
+    keeps to that extent, never the padding rows or lanes of the device
+    slabs (`slab_shape`), and reads only what it asks for, without a copy
+    of the table on the device; `np.asarray` fetches the whole of it. Every
+    other attribute (sharding, addressable_shards, devices, nbytes, ...) is
+    the padded device array's: its shards hold (1,) + `slab_shape` rows."""
+
+    ndim = 3
+
+    def __init__(self, slabs, rows: int, w: int):
+        self.slabs, self.rows, self.w = slabs, rows, w
+
+    @property
+    def shape(self) -> tuple:
+        return (self.slabs.shape[0], self.rows, self.w)
+
+    def __getattr__(self, name):
+        if name == "slabs":
+            raise AttributeError(name)
+        return getattr(self.slabs, name)
+
+    def __getitem__(self, idx):
+        idx = idx if isinstance(idx, tuple) else (idx,)
+        dots = [k for k, i in enumerate(idx) if i is Ellipsis]
+        if dots:
+            k = dots[0]
+            idx = idx[:k] + (slice(None),) * (4 - len(idx)) + idx[k + 1:]
+        idx = idx + (slice(None),) * (3 - len(idx))
+        if len(idx) > 3:
+            raise IndexError(f"{len(idx)} indices for a 3-d SlabView")
+        # the arrays and integers pick out of the padded slabs, then the
+        # slices cut what they picked: a gather whose slice ends inside the
+        # padding makes the TPU copy the whole slab (4.3 GB a chip at 2^24)
+        picks = tuple(i if isinstance(i, slice) else _wrapped(i, n)
+                      for i, n in zip(idx, self.shape))
+        out = self.slabs[tuple(slice(None) if isinstance(i, slice) else i
+                               for i in picks)]
+        cut = [slice(None)] * out.ndim
+        for k, at in _slice_landings(picks).items():
+            start, stop, step = idx[k].indices(self.shape[k])
+            cut[at] = slice(start, None if stop < 0 else stop, step)
+        return out[tuple(cut)]
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self.slabs, dtype=dtype)[:, :self.rows, :self.w]
+
+
+def _wrapped(i, n: int) -> np.ndarray:
+    """An integer or array index of an axis of logical length `n` as the
+    non-negative integers it means (a boolean mask as its positions)."""
+    i = np.asarray(i)
+    if i.dtype == bool:
+        return np.flatnonzero(i)
+    return np.where(i < 0, i + n, i)
+
+
+def _slice_landings(idx: tuple) -> dict:
+    """{axis of a slice in `idx`: its axis in the indexed result}, by
+    NumPy's rule: the picked axes take the place of adjacent picks, and
+    go first when the picks are apart."""
+    picked = [k for k, i in enumerate(idx) if not isinstance(i, slice)]
+    nb = len(np.broadcast_shapes(*(np.shape(idx[k]) for k in picked)))
+    together = picked == list(range(picked[0], picked[-1] + 1)) \
+        if picked else True
+    at, out = (0 if together else nb), {}
+    for k, i in enumerate(idx):
+        if isinstance(i, slice):
+            out[k], at = at, at + 1
+        elif together and k == picked[0]:
+            at += nb
+    return out
+
+
+@functools.lru_cache(maxsize=32)
+def _block_writer(mesh: Mesh):
+    """(zeros(shape, dtype) -> slabs, write(slabs, block (P,B,wp), lo) ->
+    slabs with every shard's rows [lo, lo+B) replaced by its block, in
+    place: the slabs are donated)."""
+    sh = NamedSharding(mesh, PS(AXIS))
+    zeros = jax.jit(lambda shape, dt: jnp.zeros(shape, dt),
+                    static_argnums=(0, 1), out_shardings=sh)
+    write = jax.jit(lambda slabs, block, lo: lax.dynamic_update_slice(
+        slabs, block, (0, lo, 0)), out_shardings=sh, donate_argnums=0)
+    return zeros, write
+
+
 def _slabs_for(store, mesh: Mesh, np_dtype, backend) -> "jnp.ndarray":
-    """The sharded residency: a (P, K_max, w) array placed so each mesh
-    shard materializes exactly the chunk rows it homes (padding rows are
-    zeros nobody addresses). Cached on the store keyed by dtype and pinned
-    to `store.version` — any host mutation invalidates it; a miss counts
-    its upload in `backend.transfer_bytes`."""
-    lay = store.shard_layout()
-    cache = store.__dict__.setdefault("_spmd_values", {})
-    ent = cache.get(str(np_dtype))
+    """The sharded residency: a (P, rows, words) array (`slab_shape`)
+    placed so each mesh shard materializes exactly the chunk rows it homes
+    (padding rows and lanes are zeros nobody reads). Cached on the store
+    keyed by dtype and pinned to `store.version` — any host mutation
+    invalidates it. A miss stages `UPLOAD_ROWS` rows of every shard at a
+    time straight from the host values (no whole-table copy on the host or
+    in transit), and counts each block in `backend.transfer_bytes`."""
+    ent = store.__dict__.get("_spmd_values", {}).get(str(np_dtype))
     if ent is not None and ent[0] == store.version:
         return ent[1]
-    host = np.zeros((store.P, lay.slab_rows, store.value_width),
-                    dtype=np_dtype)
-    live = lay.slab_keys < store.num_keys
-    host[live] = store.values[lay.slab_keys[live]].astype(np_dtype)
-    with span("backend.upload", bytes=host.nbytes):
-        dev = jax.device_put(host, NamedSharding(mesh, PS(AXIS)))
-    backend.transfer_bytes += host.nbytes
-    cache[str(np_dtype)] = (store.version, dev)
+    lay = store.shard_layout()
+    rows, w, K = lay.slab_rows, store.value_width, store.num_keys
+    shape = slab_shape(rows, w)
+    step = min(UPLOAD_ROWS, rows)
+    zeros, write = _block_writer(mesh)
+    dev = zeros((store.P,) + shape, np.dtype(np_dtype))
+    for lo in range(0, rows, step):
+        keys = lay.slab_keys[:, lo:lo + step]
+        block = np.zeros(keys.shape + shape[1:], dtype=np_dtype)
+        live = keys < K
+        block[live, :w] = store.values[keys[live]]
+        with span("backend.upload", bytes=block.nbytes):
+            dev = write(dev, jax.device_put(block, NamedSharding(
+                mesh, PS(AXIS))), np.int32(lo))
+        backend.transfer_bytes += block.nbytes
+    _pin_slabs(store, np_dtype, dev)
     return dev
 
 
-def _pin_slabs(store, np_dtype, dev) -> None:
+def _pin_slabs(store, np_dtype, dev, current: bool = True) -> None:
+    """Make `dev` the resident slabs. `current=False` holds them without
+    vouching for them: they carry writes the host copy has not taken yet,
+    so the next stage re-stages unless `apply_writes` pins them again."""
     store.__dict__.setdefault("_spmd_values", {})[str(np_dtype)] = (
-        store.version, dev)
+        store.version if current else None, dev)
+
+
+def _full_replicas(replicas) -> np.ndarray:
+    """The hot ids held by EVERY machine: only those join the replica slab
+    (a partial holders bitmap falls back to the owner fetch — values are
+    identical either way)."""
+    full = replicas.holders.all(axis=1)
+    return np.asarray(replicas.hot_ids, dtype=np.int64)[full]
 
 
 def _replica_arrays(store, replicas, np_dtype, backend):
-    """Device-side replica residency: (rep_ids (H,), lookup_ext (K+1,),
-    rep_slab (H, w)) with H pow2-padded (sentinel id = num_keys), or
-    (None, None, None) when nothing is fully replicated. Only chunks held by
-    EVERY machine join the slab (a partial holders bitmap falls back to the
-    owner fetch — values are identical either way). Cached per directory
-    object + store version; a miss counts its upload in
-    `backend.transfer_bytes`."""
+    """Device-side replica residency: (hot ids on the host, (rep_own (H,),
+    rep_slot (H,), rep_slab (H, w)) on the device) with H pow2-padded
+    (padding: owner P, slot -1), or (None, None) when nothing is fully
+    replicated. Cached per directory object + store version; a miss counts
+    its upload in `backend.transfer_bytes`."""
     if replicas is None or replicas.hot_ids.size == 0:
-        return None, None, None
-    full = replicas.holders.all(axis=1)
-    ids = np.asarray(replicas.hot_ids, dtype=np.int64)[full]
+        return None, None
+    ids = _full_replicas(replicas)
     if ids.size == 0:
-        return None, None, None
-    K = store.num_keys
-    H = _bucket(ids.size)
+        return None, None
     sig = (id(replicas), ids.size)
     cache = store.__dict__.setdefault("_spmd_replicas", {})
     ent = cache.get(str(np_dtype))
     if ent is not None and ent[0] == store.version and ent[1] == sig:
-        return ent[2]
-    rep_ids = np.full(H, K, dtype=np.int32)
-    rep_ids[:ids.size] = ids
-    lookup = np.full(K + 1, -1, dtype=np.int32)
-    lookup[ids] = np.arange(ids.size, dtype=np.int32)
+        return ids, ent[2]
+    lay = store.shard_layout()
+    H = _bucket(ids.size)
+    rep_own = np.full(H, store.P, dtype=np.int32)
+    rep_own[:ids.size] = lay.owner[ids]
+    rep_slot = np.full(H, -1, dtype=np.int32)
+    rep_slot[:ids.size] = lay.local_slot[ids]
     rep_slab = np.zeros((H, store.value_width), dtype=np_dtype)
-    rep_slab[:ids.size] = store.values[ids].astype(np_dtype)
-    out = (jnp.asarray(rep_ids), jnp.asarray(lookup), jnp.asarray(rep_slab))
-    backend.transfer_bytes += rep_ids.nbytes + lookup.nbytes + rep_slab.nbytes
+    rep_slab[:ids.size] = store.values[ids]
+    out = (jnp.asarray(rep_own), jnp.asarray(rep_slot), jnp.asarray(rep_slab))
+    backend.transfer_bytes += rep_own.nbytes + rep_slot.nbytes + rep_slab.nbytes
     cache[str(np_dtype)] = (store.version, sig, out)
-    return out
+    return ids, out
 
 
 def _pin_replicas(store, replicas, np_dtype, arrays) -> None:
-    full = replicas.holders.all(axis=1)
-    sig = (id(replicas), int(np.asarray(replicas.hot_ids)[full].size))
+    sig = (id(replicas), int(_full_replicas(replicas).size))
     store.__dict__.setdefault("_spmd_replicas", {})[str(np_dtype)] = (
         store.version, sig, arrays)
+
+
+def _replica_slot(ids: np.ndarray, keys: np.ndarray) -> np.ndarray:
+    """Each key's row in the replica slab (ids in slab order), -1 where the
+    key is not replicated (or is -1)."""
+    order = np.argsort(ids, kind="stable")
+    pos = np.clip(np.searchsorted(ids[order], keys), 0, ids.size - 1)
+    return np.where(ids[order][pos] == keys, order[pos], -1).astype(np.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -229,75 +363,94 @@ def _local_combine(u, seg, nseg, merge_name, order, rowid):
     return combined, zeros, zeros
 
 
-def _apply_to_slab(slab, combined, touched, merge_name):
-    t = touched[:, None]
+def _segments(ids, live, sentinel):
+    """Sorted distinct live `ids` (padded with `sentinel`) and each row's
+    segment among them (n for a dead row): the static-shape group-by both
+    combine sides of Phase 4 use."""
+    n = ids.shape[0]
+    key = jnp.where(live, ids, sentinel)
+    uniq = jnp.unique(key, size=n, fill_value=sentinel)
+    seg = jnp.where(live, jnp.searchsorted(uniq, key).astype(jnp.int32), n)
+    return uniq, seg
+
+
+def _apply_at(slab, rows_at, combined, merge_name, w):
+    """⊙-apply `combined` (w words a row, or one to broadcast) into the
+    slab rows `rows_at` (sorted, distinct; rows past the slab are dropped)
+    — a scatter of whole lane-padded rows over the written rows only, in
+    place when the slab is donated. The padding lanes take zeros, which
+    every ⊙ leaves at zero."""
+    n, wp = combined.shape[0], slab.shape[1]
+    combined = jnp.pad(jnp.broadcast_to(combined, (n, w)),
+                       ((0, 0), (0, wp - w)))
+    at = slab.at[rows_at]
     if merge_name == "add":
-        return slab + jnp.where(t, combined, 0)
+        return at.add(combined, mode="drop")
     if merge_name == "min":
-        return jnp.where(t, jnp.minimum(slab, combined), slab)
+        return at.min(combined, mode="drop")
     if merge_name in ("max", "or"):
-        return jnp.where(t, jnp.maximum(slab, combined), slab)
+        return at.max(combined, mode="drop")
     if merge_name == "write":
-        return jnp.where(t, combined, slab)
+        return at.set(combined, mode="drop")
     raise KeyError(f"merge op {merge_name!r} has no sharded apply")
 
 
 def build_stage_program(mesh, *, f, fwd_mask: bool, ragged: bool,
                         merge_name: str, combine: bool, want_update: bool,
-                        want_result: bool, P: int, K: int, K_max: int,
-                        T: int, Np: int, A: int, H: int, w: int, np_dtype):
+                        want_result: bool, P: int, K_max: int, T: int,
+                        Np: int, A: int, H: int, w: int, np_dtype):
     """Compile one sharded stage executable (cached by the backend per
     static signature). Array arguments, all leading-(P,·) except the
-    replicated metadata:
+    replicated replica arrays:
 
-      slabs (P,K_max,w) sharded; ctx (P,T,cw); valid (P,T); wk/order/grow
-      (P,T) int32; pkey (P,Np) int32 (flat: Np==T, pair==task);
-      ragged adds prow/pcol (P,Np) + mask (P,T,A);
-      owner_ext/slot_ext (K+1,) replicated (index K = sentinel);
-      H>0 adds rep_ids (H,), rep_lookup_ext (K+1,), rep_slab (H,w).
+      slabs (P,K_max,words) sharded and donated (`slab_shape`); ctx (P,T,cw);
+      valid (P,T);
+      wk/wown/wslot/order/grow (P,T) int32 (write key, its owner shard and
+      slab row); pown/pslot/prep (P,Np) int32 (each pair's owner shard —
+      P when inactive —, slab row and replica-slab row, -1 for none;
+      flat: Np==T, pair==task); ragged adds prow/pcol (P,Np) + mask
+      (P,T,A); H>0 adds rep_own/rep_slot (H,) and rep_slab (H,w).
     """
     dt = jnp.dtype(np_dtype)
 
-    def body(slabs, ctx, valid, wk, order, grow, pkey, prow, pcol, mask,
-             owner_ext, slot_ext, rep_ids, rep_lookup_ext, rep_slab):
+    def body(slabs, ctx, valid, wk, wown, wslot, order, grow, pown, pslot,
+             prep, prow, pcol, mask, rep_own, rep_slot, rep_slab):
         slab, ctx, valid = slabs[0], ctx[0], valid[0]
-        wk, order, grow, pkey = wk[0], order[0], grow[0], pkey[0]
+        wk, wown, wslot = wk[0], wown[0], wslot[0]
+        order, grow = order[0], grow[0]
+        pown, pslot, prep = pown[0], pslot[0], prep[0]
         me = lax.axis_index(AXIS).astype(jnp.int32)
 
-        # ---- Phase 1: contention detection (histogram + psum) -------------
+        # ---- Phase 1: contention detection (owner histogram + psum) -------
         with jax.named_scope("phase1_histogram"):
             if ragged:
                 prow_l, pcol_l, mask_l = prow[0], pcol[0], mask[0]
-                active = pkey >= 0
+                active = pown < P
             else:
-                active = valid & (pkey >= 0)
-            sent_key = jnp.where(active, pkey, K)
-            gcounts = detect_contention(sent_key, K + 1, AXIS)[:K]
-            owned = owner_ext[:K] == me
-            owned_demand = jnp.sum(jnp.where(owned, gcounts, 0))
+                active = valid & (pown < P)
+            by_owner = jnp.zeros(P + 1, jnp.int32).at[
+                jnp.where(active, pown, P)].add(1)
+            owned_demand = lax.psum(by_owner[:P], AXIS)[me]
 
         # ---- Phase 2: push-pull co-location (replica-local or a2a fetch) --
         with jax.named_scope("phase2_fetch_a2a"):
             if H > 0:
-                rep_slot = rep_lookup_ext[sent_key]
-                rep_hit = active & (rep_slot >= 0)
+                rep_hit = active & (prep >= 0)
             else:
                 rep_hit = jnp.zeros_like(active)
             need = active & ~rep_hit
-            dest = jnp.where(need, owner_ext[sent_key], P).astype(jnp.int32)
-            routing = bucket_routing(dest, P, Np, active=need)
-            req = scatter_to_buckets(
-                slot_ext[sent_key][:, None].astype(jnp.int32), routing, P, Np,
-                fill=-1)
+            routing = bucket_routing(jnp.where(need, pown, P), P, Np,
+                                     active=need)
+            req = scatter_to_buckets(pslot[:, None], routing, P, Np, fill=-1)
             recv = _a2a(req)[..., 0].reshape(P * Np)
             r_ok = recv >= 0
             reply = jnp.where(r_ok[:, None],
-                              slab[jnp.clip(recv, 0, K_max - 1)],
+                              slab[jnp.clip(recv, 0, K_max - 1)][:, :w],
                               jnp.zeros((), dt)).reshape(P, Np, w)
             fetched = gather_from_buckets(_a2a(reply), routing, Np)
             if H > 0:
                 fetched = jnp.where(rep_hit[:, None],
-                                    rep_slab[jnp.clip(rep_slot, 0, H - 1)],
+                                    rep_slab[jnp.clip(prep, 0, H - 1)],
                                     fetched)
 
         # ---- Phase 3: local execution -------------------------------------
@@ -321,26 +474,27 @@ def build_stage_program(mesh, *, f, fwd_mask: bool, ragged: bool,
         # ---- Phase 4: local ⊗-combine, a2a to owners, owner-side ⊙ --------
         n_comb_sent = n_comb_recv = jnp.zeros((), jnp.int32)
         writer = valid & (wk >= 0)
-        if combine and upd_raw is not None:
+        applied = combine and upd_raw is not None
+        new_slab = slab
+        if applied:
             with jax.named_scope("phase4_combine"):
                 u = _as_update_rows(upd_raw, T, dt)
                 uw = u.shape[1]
-                wkey = jnp.where(writer, wk, K)
-                ukeys = jnp.unique(wkey, size=T, fill_value=K)
-                seg = jnp.where(
-                    writer, jnp.searchsorted(ukeys, wkey).astype(jnp.int32),
-                    T)
+                ukeys, seg = _segments(wk, writer, _IMAX)
                 combined, pay_o, pay_r = _local_combine(
                     u, seg, T, merge_name, order, grow)
+                # every writer of a segment names the same owner and row
+                u_own = jnp.full(T, P, jnp.int32).at[seg].set(wown,
+                                                              mode="drop")
+                u_slot = jnp.full(T, -1, jnp.int32).at[seg].set(wslot,
+                                                                mode="drop")
             with jax.named_scope("phase4_a2a"):
-                uactive = ukeys < K
-                dest2 = jnp.where(uactive, owner_ext[ukeys],
-                                  P).astype(jnp.int32)
-                routing2 = bucket_routing(dest2, P, T, active=uactive)
+                uactive = ukeys < _IMAX
+                routing2 = bucket_routing(u_own, P, T, active=uactive)
                 r_rows = _a2a(scatter_to_buckets(combined, routing2, P, T))
                 r_slot = _a2a(scatter_to_buckets(
-                    slot_ext[ukeys][:, None].astype(jnp.int32), routing2, P,
-                    T, fill=-1))[..., 0].reshape(P * T)
+                    u_slot[:, None], routing2, P, T,
+                    fill=-1))[..., 0].reshape(P * T)
                 r_ord = _a2a(scatter_to_buckets(
                     pay_o[:, None], routing2, P, T,
                     fill=_IMAX))[..., 0].reshape(P * T)
@@ -349,26 +503,23 @@ def build_stage_program(mesh, *, f, fwd_mask: bool, ragged: bool,
                     fill=_IMAX))[..., 0].reshape(P * T)
             with jax.named_scope("phase4_apply"):
                 r_live = r_slot >= 0
-                seg2 = jnp.where(r_live, r_slot, K_max)
+                rows_at, seg2 = _segments(r_slot, r_live, K_max)
                 comb2, _, _ = _local_combine(r_rows.reshape(P * T, uw), seg2,
-                                             K_max, merge_name, r_ord, r_row)
-                touched = jnp.zeros(K_max, jnp.int32).at[seg2].add(
-                    1, mode="drop") > 0
-                new_slab = _apply_to_slab(slab, comb2, touched, merge_name)
+                                             P * T, merge_name, r_ord, r_row)
+                new_slab = _apply_at(slab, rows_at, comb2, merge_name, w)
                 n_comb_sent = jnp.sum(uactive.astype(jnp.int32))
                 n_comb_recv = jnp.sum(r_live.astype(jnp.int32))
-        else:
-            new_slab = slab
 
         # ---- replica write-through: owners broadcast post-apply rows ------
         with jax.named_scope("phase4_apply"):
-            if H > 0 and combine and upd_raw is not None:
-                rep_live = rep_ids < K
-                rep_local = jnp.clip(slot_ext[rep_ids], 0, K_max - 1)
-                mine = rep_live & (owner_ext[rep_ids] == me)
-                rep_touch = mine & touched[rep_local]
-                contrib = jnp.where(rep_touch[:, None], new_slab[rep_local],
-                                    jnp.zeros((), dt))
+            if H > 0 and applied:
+                at = jnp.clip(jnp.searchsorted(rows_at, rep_slot), 0,
+                              P * T - 1)
+                rep_touch = (rep_own == me) & (rows_at[at] == rep_slot)
+                contrib = jnp.where(
+                    rep_touch[:, None],
+                    new_slab[jnp.clip(rep_slot, 0, K_max - 1)][:, :w],
+                    jnp.zeros((), dt))
                 tmask = lax.psum(rep_touch.astype(jnp.int32), AXIS) > 0
                 rep_new = jnp.where(tmask[:, None], lax.psum(contrib, AXIS),
                                     rep_slab)
@@ -393,16 +544,43 @@ def build_stage_program(mesh, *, f, fwd_mask: bool, ragged: bool,
             n_comb_sent, n_comb_recv,
             owned_demand.astype(jnp.int32),
         ])
-        return (res[None], upd[None], new_slab[None], rep_new, stats[None])
+        # results and updates leave as the shards' rows stacked, (P*T, ...):
+        # a TPU keeps such arrays row-major, where a (1, T, w) block per
+        # shard would come home through a relayout
+        return (res, upd, new_slab[None], rep_new, stats[None])
 
     sh = PS(AXIS)
     rep = PS()
     fn = jax.shard_map(
         body, mesh=mesh,
-        in_specs=(sh, sh, sh, sh, sh, sh, sh, sh, sh, sh,
-                  rep, rep, rep, rep, rep),
+        in_specs=(sh,) * 14 + (rep,) * 3,
         out_specs=(sh, sh, sh, rep, sh))
-    return jax.jit(fn)
+    return jax.jit(fn, donate_argnums=0)
+
+
+def exchange_bytes(P: int, Np: int, T: int, w: int, uw: int, itemsize: int,
+                   phase4: bool) -> int:
+    """Bytes of one stage's all-to-all send buffers as compiled (padded to
+    their pow2 capacities), summed over the P shards: Phase 2's slab-row
+    requests (int32) and replies (w words), and, when the stage applies
+    writes, Phase 4's combined rows (uw words) with their slab row, order
+    and row id (int32 each)."""
+    per_shard = P * Np * (4 + w * itemsize)
+    if phase4:
+        per_shard += P * T * (uw * itemsize + 3 * 4)
+    return P * per_shard
+
+
+@functools.lru_cache(maxsize=32)
+def _row_gather(mesh: Mesh, w: int):
+    """(slabs (P,K_max,wp), rows (P,B)) -> (P*B,w): each shard reads its own
+    slab rows — the write-back's fetch, local to every shard."""
+    def body(slabs, rows):
+        slab = slabs[0]
+        return slab[jnp.clip(rows[0], 0, slab.shape[0] - 1)][:, :w]
+
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(PS(AXIS),) * 2,
+                                 out_specs=PS(AXIS)))
 
 
 # ---------------------------------------------------------------------------
@@ -424,14 +602,24 @@ def place_tasks(exec_site: np.ndarray, P: int) -> ShardPlacement:
                           T_cap=_bucket(int(counts.max(initial=1))))
 
 
+def _key_maps(lay, keys: np.ndarray, P: int):
+    """(owner shard, slab row) of each key as int32; (P, -1) where the key
+    is -1."""
+    has = keys >= 0
+    k = np.where(has, keys, 0)
+    own = np.where(has, lay.owner[k], P).astype(np.int32)
+    slot = np.where(has, lay.local_slot[k], -1).astype(np.int32)
+    return own, slot
+
+
 def run_sharded_stage(backend, tasks, store, f, merge,
                       want_result: bool, combine: bool, want_update: bool,
                       exec_site: Optional[np.ndarray],
                       replicas) -> Dict[str, object]:
     """Execute one stage's numerics over the device mesh. Returns the
     backend-facing dict: host `result`/`update` rows (in original task
-    order), plus the apply carry (`uniq`, device `new_slabs`/replica slab)
-    and the measured `ShardStageStats`."""
+    order), the apply carry (device `new_slabs`/replica slab), the measured
+    `ShardStageStats` and the stage's all-to-all bytes and rows."""
     P = store.P
     mesh = get_mesh(P)
     lay = store.shard_layout()
@@ -451,6 +639,10 @@ def run_sharded_stage(backend, tasks, store, f, merge,
         valid[pl.shard, pl.slot] = True
         wk = np.full((P, T), -1, dtype=np.int32)
         wk[pl.shard, pl.slot] = tasks.write_keys
+        wown = np.full((P, T), P, dtype=np.int32)
+        wslot = np.full((P, T), -1, dtype=np.int32)
+        wown[pl.shard, pl.slot], wslot[pl.shard, pl.slot] = _key_maps(
+            lay, tasks.write_keys, P)
         order = np.zeros((P, T), dtype=np.int32)
         order[pl.shard, pl.slot] = np.clip(tasks.priority, -2**31, 2**31 - 1)
         grow = np.full((P, T), n, dtype=np.int32)
@@ -462,39 +654,41 @@ def run_sharded_stage(backend, tasks, store, f, merge,
             pair_shard = pl.shard[tasks.pair_task]
             pair_col = np.arange(tasks.nnz, dtype=np.int64) \
                 - tasks.read_indptr[:-1][tasks.pair_task]
-            pslot, pcounts = stable_bucket_slots(pair_shard, P)
+            pslot_ix, pcounts = stable_bucket_slots(pair_shard, P)
             Np = _bucket(int(pcounts.max(initial=1)))
-            pkey = np.full((P, Np), -1, dtype=np.int32)
-            pkey[pair_shard, pslot] = tasks.read_indices
+            at = (pair_shard, pslot_ix)
+            keys = tasks.read_indices
             prow = np.full((P, Np), T, dtype=np.int32)
-            prow[pair_shard, pslot] = pl.slot[tasks.pair_task]
+            prow[at] = pl.slot[tasks.pair_task]
             pcol = np.zeros((P, Np), dtype=np.int32)
-            pcol[pair_shard, pslot] = pair_col
+            pcol[at] = pair_col
             mask = np.zeros((P, T, A), dtype=bool)
             mask[pair_shard, pl.slot[tasks.pair_task], pair_col] = True
         else:
             Np = T
-            pkey = np.full((P, T), -1, dtype=np.int32)
-            pkey[pl.shard, pl.slot] = tasks.read_keys
+            at = (pl.shard, pl.slot)
+            keys = tasks.read_keys
             prow = pcol = np.zeros((P, 1), dtype=np.int32)
             mask = np.zeros((P, 1, 1), dtype=bool)
+        pown = np.full((P, Np), P, dtype=np.int32)
+        pslot = np.full((P, Np), -1, dtype=np.int32)
+        pown[at], pslot[at] = _key_maps(lay, keys, P)
 
-        K = store.num_keys
-        owner_ext = np.concatenate(
-            [lay.owner.astype(np.int32), np.int32([P])])
-        slot_ext = np.concatenate(
-            [lay.local_slot.astype(np.int32), np.int32([lay.slab_rows])])
-        rep_ids, rep_lookup_ext, rep_slab = _replica_arrays(
-            store, replicas, np_dtype, backend)
-        H = 0 if rep_ids is None else int(rep_ids.shape[0])
-        if H == 0:
-            rep_ids = jnp.zeros(1, jnp.int32)
-            rep_lookup_ext = jnp.zeros(1, jnp.int32)
-            rep_slab = jnp.zeros((1, store.value_width), np_dtype)
+        rep_ids, rep_dev = _replica_arrays(store, replicas, np_dtype, backend)
+        H = 0 if rep_ids is None else int(rep_dev[0].shape[0])
+        if H:
+            prep = np.full((P, Np), -1, dtype=np.int32)
+            prep[at] = _replica_slot(rep_ids, keys)
+            rep_own, rep_slot, rep_slab = rep_dev
+        else:
+            prep = np.zeros((P, 1), dtype=np.int32)
+            rep_own = rep_slot = np.zeros(1, dtype=np.int32)
+            rep_slab = np.zeros((1, store.value_width), dtype=np_dtype)
 
     fwd = execution._accepts_mask(f)
+    K_max = slab_shape(lay.slab_rows, store.value_width)[0]
     sig = (id(f), fwd, ragged, merge.name if merge is not None else None,
-           combine, want_update, want_result, P, K, lay.slab_rows, T, Np, A,
+           combine, want_update, want_result, P, K_max, T, Np, A,
            H, store.value_width, ctx_np.shape[1:], str(np_dtype))
     prog = backend._programs.get(sig)
     if prog is None:
@@ -502,50 +696,75 @@ def run_sharded_stage(backend, tasks, store, f, merge,
             mesh, f=f, fwd_mask=fwd, ragged=ragged,
             merge_name=merge.name if merge is not None else "add",
             combine=combine, want_update=want_update,
-            want_result=want_result, P=P, K=K, K_max=lay.slab_rows, T=T,
+            want_result=want_result, P=P, K_max=K_max, T=T,
             Np=Np, A=A, H=H, w=store.value_width, np_dtype=np_dtype)
 
     slabs = _slabs_for(store, mesh, np_dtype, backend)
-    # the program uploads its host operands: the per-shard blocks once, the
-    # replicated key maps to every shard
-    host_ops = (ctx, valid, wk, order, grow, pkey, prow, pcol, mask)
-    backend.transfer_bytes += (sum(a.nbytes for a in host_ops)
-                               + P * (owner_ext.nbytes + slot_ext.nbytes))
+    # the program uploads its host operands: each shard its own block of
+    # the per-shard ones, every shard the (dummy) replica arrays
+    host_ops = (ctx, valid, wk, wown, wslot, order, grow, pown, pslot, prep,
+                prow, pcol, mask)
+    rep_ops = (rep_own, rep_slot, rep_slab)
+    backend.transfer_bytes += sum(a.nbytes for a in host_ops) + (
+        0 if H else P * sum(a.nbytes for a in rep_ops))
     try:
         with span("backend.dispatch"):
             res_d, upd_d, new_slabs, rep_new, stats_d = prog(
-                slabs, *host_ops, owner_ext, slot_ext, rep_ids,
-                rep_lookup_ext, rep_slab)
+                slabs, *host_ops, *rep_ops)
     except UNTRACEABLE as e:
         # only an untraceable lambda is fallback-eligible (mirrors the jax
-        # backend, whose try covers exactly the jitted stage call)
+        # backend, whose try covers exactly the jitted stage call); tracing
+        # failed, so nothing ran and the slabs were not donated
         raise ShardStageError(
             f"sharded stage lambda is not traceable: {e}") from e
+    # the stage donated the resident slabs; its output takes their place.
+    # Slabs that took writes wait for apply_writes to vouch for them.
+    uw = int(upd_d.shape[-1])
+    applied = combine and uw > 0
+    _pin_slabs(store, np_dtype, new_slabs, current=not applied)
 
     stats_np = backend._fetch(stats_d)
     backend.host_syncs += 1
     stats = ShardStageStats(*(stats_np[:, i].astype(np.int64)
                               for i in range(stats_np.shape[1])))
 
-    out: Dict[str, object] = {"result": None, "update": None,
-                              "new_slabs": new_slabs, "stats": stats,
-                              "rep_arrays": None,
-                              "update_width": int(upd_d.shape[-1])}
+    out: Dict[str, object] = {
+        "result": None, "update": None, "new_slabs": new_slabs,
+        "stats": stats, "rep_arrays": None, "update_width": uw,
+        "exchange_bytes": exchange_bytes(P, Np, T, store.value_width, uw,
+                                         np.dtype(np_dtype).itemsize,
+                                         applied),
+        "exchange_rows": int(stats.fetch_sent.sum() + stats.fetch_recv.sum()
+                             + stats.combine_sent.sum())}
     if H > 0:
-        out["rep_arrays"] = (rep_ids, rep_lookup_ext, rep_new)
-    # res_d is (P, T) for a 1-D lambda result, (P, T, rw) otherwise
+        out["rep_arrays"] = (rep_own, rep_slot, rep_new)
+    # res_d is (P*T,) for a 1-D lambda result, (P*T, rw) otherwise
     # (rw == 0 means the lambda returned no result at all)
-    if want_result and (res_d.ndim == 2 or res_d.shape[-1] > 0):
-        out["result"] = backend._fetch(res_d)[pl.shard, pl.slot]
-        backend.host_syncs += 1
-    if want_update and upd_d.shape[-1] > 0:
-        out["update"] = backend._fetch(upd_d)[pl.shard, pl.slot]
-        backend.host_syncs += 1
+    for name, dev, want in (("result", res_d, want_result and (
+            res_d.ndim == 1 or res_d.shape[-1] > 0)),
+            ("update", upd_d, want_update and uw > 0)):
+        if want:
+            rows = backend._fetch(dev)
+            out[name] = rows.reshape((P, T) + rows.shape[1:])[pl.shard,
+                                                              pl.slot]
+            backend.host_syncs += 1
     return out
 
 
-def gather_slab_rows(store, new_slabs, keys: np.ndarray):
-    """The post-apply rows for `keys` gathered out of the sharded slabs
-    (one cross-device gather; the caller fetches them to the host)."""
+def fetch_slab_rows(backend, store, slabs, keys: np.ndarray) -> np.ndarray:
+    """The rows of `keys` read out of the sharded slabs to the host: each
+    shard gathers its own rows into a (B,) block (B the pow2 bucket of the
+    largest shard's share, so batches reuse one compiled gather), and one
+    fetch brings the blocks home. Counts its transfers and one host
+    sync."""
     lay = store.shard_layout()
-    return new_slabs[lay.owner[keys], lay.local_slot[keys]]
+    own = lay.owner[keys]
+    pos, counts = stable_bucket_slots(own, store.P)
+    at = np.zeros((store.P, _bucket(int(counts.max(initial=1)))),
+                  dtype=np.int32)
+    at[own, pos] = lay.local_slot[keys]
+    backend.transfer_bytes += at.nbytes
+    rows = backend._fetch(_row_gather(get_mesh(store.P), store.value_width)(
+        slabs, at))
+    backend.host_syncs += 1
+    return rows.reshape(at.shape + rows.shape[1:])[own, pos]

@@ -203,7 +203,7 @@ def test_spmd_stats_read_is_counted():
         import numpy as np
         from repro.core import DataStore, Orchestrator, TaskBatch
         from repro.core.backend import _bucket_rows
-        P, K, W, n = 4, 2048, 3, 32  # K past the device histogram
+        P, K, W, n = 4, 2048, 3, 32
 
         def get(c, v):
             return {"result": v}
@@ -222,10 +222,12 @@ def test_spmd_stats_read_is_counted():
         assert bk.host_syncs - syncs == 2, bk.host_syncs - syncs
         T = _bucket_rows(int(stats.tasks.max()))  # task slots a shard
         fetched = P * T * W * 4 + P * len(stats) * 4  # results + stats
-        # ctx (1 word), valid, writer keys, order, row ids, read keys
-        uploaded = (P * T * (4 + 1 + 4 + 4 + 4 + 4)
+        # ctx (1 word), valid, writer keys with their owners and slab rows,
+        # order, row ids, read keys' owners and slab rows
+        uploaded = (P * T * (4 + 1 + 3 * 4 + 4 + 4 + 2 * 4)
+                    + P * 4  # replica rows, 1 slot (nothing replicated)
                     + 2 * P * 4 + P * 1  # ragged-only operands, 1 slot each
-                    + P * 2 * 4 * (K + 1))  # owner and slot maps, per shard
+                    + P * (4 + 4 + W * 4))  # replica dummies, every shard
         assert bk.transfer_bytes - moved == fetched + uploaded, (
             bk.transfer_bytes - moved, fetched + uploaded)
         print("OK")

@@ -12,13 +12,20 @@ where the collectives actually cross shards. The Zipf load-balance
 assertion (the ROADMAP's "sharding" axis as a number) only runs with >= 8
 devices.
 """
+import os
+import pathlib
+import subprocess
+import sys
+import textwrap
+
 import numpy as np
 import pytest
 
 import jax
 
 from repro.core import (DataStore, Orchestrator, TaskBatch,
-                        assert_cost_parity, make_backend)
+                        assert_cost_parity, make_backend, shardexec)
+from repro.core.backend import _bucket_rows
 
 NDEV = len(jax.devices())
 P = min(4, NDEV)
@@ -348,3 +355,278 @@ def test_zipf_skew_balance_with_replication():
         sess.run_stage(tasks, _muladd, write_back="write")
     pm = sess.report.per_machine()
     assert pm["work_ratio"] <= 1.5, pm["work_ratio"]
+
+
+# ---------------------------------------------------------------------------
+# a warm stage is sized by the batch, not by the table
+# ---------------------------------------------------------------------------
+def _on_four_devices(name: str) -> None:
+    """Run the check `name` (a function of this module) on a four-shard
+    mesh: here when the process has four devices, else in a child process
+    with four virtual CPU devices."""
+    if NDEV >= 4:
+        globals()[name]()
+        return
+    here = pathlib.Path(__file__).resolve().parent
+    code = textwrap.dedent(f"""
+        import os, sys
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        sys.path.insert(0, {str(here)!r})
+        import test_spmd_backend
+        test_spmd_backend.{name}()
+        print("OK")
+    """)
+    env = dict(os.environ, PYTHONPATH=str(here.parent / "src"))
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert "OK" in out.stdout, out.stderr[-3000:]
+
+
+def _recorded_programs():
+    """Wrap `shardexec.build_stage_program` so every stage call records its
+    non-slab operand shapes and the compiled program's temp bytes (lowered
+    before the call: the call donates the slabs)."""
+    calls = []
+    build = shardexec.build_stage_program
+
+    def recording_build(*args, **kw):
+        prog = build(*args, **kw)
+
+        def run(*a):
+            mem = prog.lower(*a).compile().memory_analysis()
+            calls.append(([np.shape(x) for x in a[1:]],
+                          None if mem is None else mem.temp_size_in_bytes))
+            return prog(*a)
+        return run
+
+    shardexec.build_stage_program = recording_build
+    return calls, lambda: setattr(shardexec, "build_stage_program", build)
+
+
+def _warm_stage(K, batches, f, merge):
+    """Run `batches` over a fresh K-key store; the last stage's counter
+    deltas and its program's recorded operand shapes and temp bytes."""
+    bk = make_backend("jax_spmd")
+    store = DataStore.create(K, P, value_width=3, chunk_words=3)
+    store.write_rows(np.arange(K),
+                     np.random.default_rng(K).standard_normal((K, 3)))
+    sess = Orchestrator(store, engine="tdorch", backend=bk)
+    calls, restore = _recorded_programs()
+    try:
+        for t in batches[:-1]:
+            sess.run_stage(t, f, write_back=merge, return_results=True)
+        before = (bk.transfer_bytes, bk.exchange_bytes, bk.exchange_rows,
+                  bk.host_syncs)
+        sess.run_stage(batches[-1], f, write_back=merge, return_results=True)
+    finally:
+        restore()
+    after = (bk.transfer_bytes, bk.exchange_bytes, bk.exchange_rows,
+             bk.host_syncs)
+    return [b - a for a, b in zip(before, after)], calls[-1]
+
+
+def check_warm_stage_independent_of_table():
+    """The same batches over 2^12 and 2^16 keys: the warm stage moves the
+    same bytes to and from the host and through the all-to-alls, takes
+    the same non-slab operands and needs the same temp memory."""
+    for f, merge, make in ((_muladd, "write", _arity1_batches),
+                           (_masked_sum, "add", _ragged_batches)):
+        small = _warm_stage(1 << 12, make(K=1 << 12), f, merge)
+        big = _warm_stage(1 << 16, make(K=1 << 12), f, merge)
+        assert small[0] == big[0], (merge, small[0], big[0])
+        assert small[1][0] == big[1][0], merge
+        if small[1][1] is not None:  # the backend reports temp bytes
+            assert small[1][1] == big[1][1], merge
+
+
+def check_stage_stats_match_key_histogram():
+    """Every `ShardStageStats` field equals its derivation from the batch,
+    `owned_demand` from the K-bin histogram of requested keys."""
+    K = 60
+    bk = make_backend("jax_spmd")
+    store = _make_store(K=K, seed=31)
+    owner = store.shard_layout().owner
+    sess = Orchestrator(store, engine="tdorch", backend=bk)
+    for t in _arity1_batches(K=K, stages=3, seed=32):
+        res = sess.run_stage(t, _muladd, write_back="write",
+                             return_results=True)
+        st = bk.stage_stats[-1]
+        site = res.exec_site
+        active = t.read_keys >= 0
+        hist = np.bincount(t.read_keys[active], minlength=K)
+        demand = np.array([hist[owner == m].sum() for m in range(P)])
+        w = t.write_keys >= 0
+        sent = [np.unique(t.write_keys[w & (site == m)]) for m in range(P)]
+        recv = np.zeros(P, dtype=np.int64)
+        for keys in sent:
+            recv += np.bincount(owner[keys], minlength=P)
+        want = dict(
+            tasks=np.bincount(site, minlength=P),
+            pairs=np.bincount(site[active], minlength=P),
+            fetch_sent=np.bincount(site[active], minlength=P),
+            fetch_recv=demand, replica_local=np.zeros(P, np.int64),
+            writers=np.bincount(site[w], minlength=P),
+            combine_sent=np.array([k.size for k in sent]),
+            combine_recv=recv, owned_demand=demand)
+        for field, value in want.items():
+            assert np.array_equal(getattr(st, field), value), field
+
+
+def check_write_ties_resolve_as_oracle():
+    """Writers of one key on different shards with tied priorities: the
+    lowest global row wins, as in the numpy oracle."""
+    K, n = 8, 96
+    rng = np.random.default_rng(41)
+    keys = rng.integers(0, 3, n)
+    ctx = np.concatenate([np.zeros((n, 1)), rng.standard_normal((n, 2))],
+                         axis=1)
+    prio = rng.integers(0, 2, n)
+
+    def run(backend):
+        store = _make_store(K=K, seed=42)
+        sess = Orchestrator(store, engine="pull", backend=backend)
+        t = TaskBatch(contexts=ctx, read_keys=keys, write_keys=keys,
+                      priority=prio, origin=TaskBatch.even_origins(n, P))
+        res = sess.run_stage(t, _muladd, write_back="write")
+        return store.values, res.exec_site
+
+    want, _ = run("numpy")
+    got, site = run(make_backend("jax_spmd"))
+    assert np.allclose(got, want, rtol=RTOL, atol=ATOL)
+    # the test means something: a key's winning priority is held by tasks
+    # on at least two shards
+    k0 = keys == 0
+    top = k0 & (prio == prio[k0].min())
+    assert np.unique(site[top]).size >= 2
+
+
+def check_blockwise_upload_equals_whole_table(block_rows=5):
+    """Slabs staged a few rows of every shard at a time hold exactly what
+    staging the whole table at once would."""
+    store = _make_store(K=61, seed=43)
+    lay = store.shard_layout()
+    bk = make_backend("jax_spmd")
+    keep, shardexec.UPLOAD_ROWS = shardexec.UPLOAD_ROWS, block_rows
+    try:
+        slabs = shardexec._slabs_for(store, shardexec.get_mesh(P),
+                                     np.float32, bk)
+    finally:
+        shardexec.UPLOAD_ROWS = keep
+    live = lay.slab_keys < store.num_keys
+    rows, words = shardexec.slab_shape(lay.slab_rows, 3)
+    whole = np.zeros((P, rows, words), dtype=np.float32)
+    whole[:, :lay.slab_rows][live, :3] = store.values[lay.slab_keys[live]]
+    assert lay.slab_rows % block_rows  # the last block is a short one
+    np.testing.assert_array_equal(np.asarray(slabs), whole)
+    assert bk.transfer_bytes == P * lay.slab_rows * words * 4
+
+
+def check_exchange_counters_by_hand():
+    """A YCSB-like stage's all-to-all bytes (padded buffers) and live rows,
+    counted by hand; the stage syncs with the host three times, as before
+    (statistics, results, the written rows)."""
+    K, W, n = 64, 3, 40
+    bk = make_backend("jax_spmd")
+    store = _make_store(K=K, w=W, seed=44)
+    sess = Orchestrator(store, engine="tdorch", backend=bk)
+    batches = _arity1_batches(K=K, n=n, stages=2, seed=45)
+    sess.run_stage(batches[0], _muladd, write_back="write")
+    before = (bk.exchange_bytes, bk.exchange_rows, bk.host_syncs)
+    t = batches[1]
+    res = sess.run_stage(t, _muladd, write_back="write", return_results=True)
+    site = res.exec_site
+    T = _bucket_rows(int(np.bincount(site, minlength=P).max()))
+    fetch = P * T * (4 + W * 4)  # slab rows asked for, rows sent back
+    write = P * T * (W * 4 + 3 * 4)  # rows, slab row, order, row id
+    w = t.write_keys >= 0
+    combined = sum(np.unique(t.write_keys[w & (site == m)]).size
+                   for m in range(P))
+    pairs = int((t.read_keys >= 0).sum())
+    assert bk.exchange_bytes - before[0] == P * (fetch + write)
+    assert bk.exchange_rows - before[1] == 2 * pairs + combined
+    assert bk.host_syncs - before[2] == 3
+
+
+def check_chip_smoke_mesh_phase():
+    """`chip_smoke.py --chips 4`'s mesh phase, cut to a 1,000-record table
+    and small batches, passes against its own reference: it reads every
+    device shard of the padded slabs."""
+    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
+    import chip_smoke
+
+    chip_smoke.YCSB_OPS = 1 << 10
+    chip_smoke.spmd_phase(seed=5, chips=P, keys_per_chip=250)
+
+
+@pytest.mark.parametrize("check", [
+    "check_warm_stage_independent_of_table",
+    "check_stage_stats_match_key_histogram",
+    "check_write_ties_resolve_as_oracle",
+    "check_blockwise_upload_equals_whole_table",
+    "check_exchange_counters_by_hand",
+    "check_chip_smoke_mesh_phase",
+])
+def test_on_a_four_shard_mesh(check):
+    _on_four_devices(check)
+
+
+@pytest.mark.parametrize("writes", [True, False], ids=["writes", "reads"])
+def test_resident_slab_is_the_stage_output(writes):
+    """The stage donates the resident slabs and its output takes their
+    place, whether or not it wrote; the resident values are the host
+    values at their slab rows."""
+    store = _make_store(seed=46)
+    bk = make_backend("jax_spmd")
+    sess = Orchestrator(store, engine="tdorch", backend=bk)
+    b0, b1 = _arity1_batches(K=60, stages=2, seed=47)
+    sess.run_stage(b0, _muladd, write_back="write")
+    before = bk._slabs(store)
+    if not writes:
+        b1 = TaskBatch(contexts=b1.contexts, read_keys=b1.read_keys,
+                       write_keys=np.full(b1.n, -1), origin=b1.origin)
+    sess.run_stage(b1, _muladd, write_back="write", return_results=True)
+    after = bk._slabs(store)
+    assert after is not before and before.is_deleted()
+    assert store.__dict__["_spmd_values"]["float32"][0] == store.version
+    lay = store.shard_layout()
+    view = bk.resident(store)
+    assert view.shape == (P, lay.slab_rows, store.value_width)
+    got = np.asarray(view)[lay.owner, lay.local_slot]
+    np.testing.assert_allclose(got, store.values, rtol=RTOL, atol=ATOL)
+    # read as the benchmark's check reads it: columns of every row, rows
+    cols, keys = np.array([0, 2]), np.array([5, 17, 42])
+    by_col = np.asarray(view[:, :, cols])[lay.owner, lay.local_slot]
+    by_row = np.asarray(view[lay.owner[keys], lay.local_slot[keys]])
+    np.testing.assert_array_equal(by_col, got[:, cols])
+    np.testing.assert_array_equal(by_row, got[keys])
+
+
+@pytest.mark.parametrize("idx", [
+    (slice(None), slice(None), np.array([0, 2])),
+    (np.array([0, P - 1, P - 1]), np.array([3, 0, 14])),
+    (Ellipsis, 1),
+    (P - 1,),
+    (slice(None), -1),
+    (slice(None), slice(None, None, -3), slice(1, None)),
+    (np.array([True, False, True, False])[:P], slice(2, 9)),
+    (0, np.array([-1, -2]), Ellipsis),
+], ids=["cols", "rows", "ellipsis", "shard", "last_row", "neg_step",
+        "mask", "neg_rows"])
+def test_slab_view_reads_as_the_logical_array(idx):
+    """The resident view indexes like the (P, slab_rows, w) array it holds,
+    never reaching the tile padding of the device slabs; the rest of the
+    array's surface is the padded device array's."""
+    store = _make_store(K=61, seed=48)
+    bk = make_backend("jax_spmd")
+    Orchestrator(store, engine="tdorch", backend=bk).run_stage(
+        _arity1_batches(K=61, stages=1, seed=49)[0], _muladd,
+        write_back="write")
+    view = bk.resident(store)
+    whole = np.asarray(view)
+    assert whole.shape == view.shape
+    idx = idx[0] if len(idx) == 1 else idx
+    np.testing.assert_array_equal(np.asarray(view[idx]), whole[idx])
+    slabs = bk._slabs(store)
+    assert view.sharding == slabs.sharding and view.nbytes == slabs.nbytes
+    assert len(view.addressable_shards) == P

@@ -169,30 +169,91 @@ def _muladd(contexts, in_vals):
             "result": in_vals}
 
 
-def test_sharded_stage_compiles_on_four_chips(topo):
-    """YCSB-A as the four-chip smoke runs it: 2^22 records of 250 words
-    over 4 machines (≈1 GiB of slab per chip), 2^16 operations."""
-    P, K, w = 4, 1 << 22, 250
-    K_max, T = (K // P) + (1 << 12), 1 << 15
+def _sharded_stage(topo, K):
+    """The YCSB-A stage of the four-chip cell compiled for a v5e:2x2: K
+    records of 250 words over 4 machines (the largest shard homing a few
+    thousand more than a quarter, an odd count), 2^16 operations (2^15
+    task slots a shard)."""
+    P, w, T = 4, 250, 1 << 15
+    K_max, words = shardexec.slab_shape((K // P) + 2182, w)
     mesh = jax.sharding.Mesh(np.array(topo.devices[:P]), (shardexec.AXIS,))
     sh = NamedSharding(mesh, PartitionSpec(shardexec.AXIS))
     rep = NamedSharding(mesh, PartitionSpec())
     prog = shardexec.build_stage_program(
         mesh, f=_muladd, fwd_mask=False, ragged=False, merge_name="write",
-        combine=True, want_update=False, want_result=True, P=P, K=K,
+        combine=True, want_update=False, want_result=True, P=P,
         K_max=K_max, T=T, Np=T, A=1, H=0, w=w, np_dtype=np.float32)
     i32, f32 = jnp.int32, jnp.float32
-    specs = (_spec((P, K_max, w), f32, sh), _spec((P, T, 3), f32, sh),
-             _spec((P, T), jnp.bool_, sh), _spec((P, T), i32, sh),
-             _spec((P, T), i32, sh), _spec((P, T), i32, sh),
-             _spec((P, T), i32, sh), _spec((P, 1), i32, sh),
+    per_task = [_spec((P, T), i32, sh) for _ in range(7)]
+    slabs = _spec((P, K_max, words), f32, sh)
+    specs = (slabs, _spec((P, T, 3), f32, sh),
+             _spec((P, T), jnp.bool_, sh), *per_task,
+             _spec((P, 1), i32, sh), _spec((P, 1), i32, sh),
              _spec((P, 1), i32, sh), _spec((P, 1, 1), jnp.bool_, sh),
-             _spec((K + 1,), i32, rep), _spec((K + 1,), i32, rep),
              _spec((1,), i32, rep), _spec((1,), i32, rep),
              _spec((1, w), f32, rep))
-    compiled = prog.lower(*specs).compile()
-    text = compiled.as_text()
-    assert "all-to-all" in text
+    return prog.lower(*specs).compile(), K_max * w * 4
+
+
+def test_sharded_stage_compiles_on_four_chips(topo):
+    """2^24 records (4.2 GB of slab a chip): the stage updates the donated
+    slab in place, and beside it needs what the batch needs, whatever the
+    table's size."""
+    compiled, slab = _sharded_stage(topo, 1 << 24)
+    assert "all-to-all" in compiled.as_text()
     mem = compiled.memory_analysis()
-    # each chip holds its quarter of the table, not the whole of it
-    assert mem.argument_size_in_bytes < K * w * 4 // 2
+    # each chip holds its quarter of the table (rows padded to 256 lanes),
+    # not the whole of it
+    assert mem.argument_size_in_bytes < 1.1 * slab
+    assert mem.alias_size_in_bytes >= slab  # the output slab is the input
+    peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert peak < 1.3 * slab
+    # the temporaries do not grow with the table (the compiler picks a
+    # different schedule for small slabs, with more of them)
+    small, _ = _sharded_stage(topo, 1 << 20)
+    assert mem.temp_size_in_bytes <= small.memory_analysis().temp_size_in_bytes
+    assert mem.temp_size_in_bytes < 0.1 * slab
+
+
+@pytest.mark.parametrize("program", ["upload_block", "row_gather"])
+def test_slab_programs_compile_on_four_chips(topo, program):
+    """The slab's upload writes each block in place, and the write-back's
+    row gather reads only the rows asked for, at 2^24 records."""
+    P, w = 4, 250
+    K_max, wp = shardexec.slab_shape((1 << 22) + 2182, w)
+    mesh = jax.sharding.Mesh(np.array(topo.devices[:P]), (shardexec.AXIS,))
+    sh = NamedSharding(mesh, PartitionSpec(shardexec.AXIS))
+    slabs = _spec((P, K_max, wp), jnp.float32, sh)
+    if program == "upload_block":
+        _, write = shardexec._block_writer(mesh)
+        block = _spec((P, shardexec.UPLOAD_ROWS, wp), jnp.float32, sh)
+        mem = write.lower(slabs, block, jax.ShapeDtypeStruct(
+            (), jnp.int32)).compile().memory_analysis()
+        assert mem.alias_size_in_bytes == mem.output_size_in_bytes
+    else:
+        rows = _spec((P, 8192), jnp.int32, sh)
+        mem = shardexec._row_gather(mesh, w).lower(
+            slabs, rows).compile().memory_analysis()
+        assert mem.output_size_in_bytes < 8192 * 256 * 4 * 1.01
+    assert mem.temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("read", ["columns", "rows"])
+def test_slab_view_reads_compile_on_four_chips(topo, read):
+    """The check's two reads of the resident table through `SlabView` at
+    2^24 records (a few columns of every slab row, every word of a few
+    rows) need no copy of the slab beside it."""
+    P, w, rows = 4, 250, (1 << 22) + 2182
+    K_max, wp = shardexec.slab_shape(rows, w)
+    mesh = jax.sharding.Mesh(np.array(topo.devices[:P]), (shardexec.AXIS,))
+    slabs = _spec((P, K_max, wp), jnp.float32,
+                  NamedSharding(mesh, PartitionSpec(shardexec.AXIS)))
+    rng = np.random.default_rng(0)
+    if read == "columns":
+        idx = (slice(None), slice(None), np.sort(rng.choice(w, 4, False)))
+    else:
+        idx = (rng.integers(0, P, 512), rng.integers(0, rows, 512))
+    mem = jax.jit(lambda s: shardexec.SlabView(s, rows, w)[idx]).lower(
+        slabs).compile().memory_analysis()
+    assert mem.temp_size_in_bytes < 0.15 * K_max * wp * 4
