@@ -22,7 +22,13 @@ and destination `TreeCharger`s; direct calls borrow the graph's cached
 default session instead of rebuilding the layout per call. A round whose
 active edges are all the graph's edges charges its write-back on the
 ingest-time destination trees (`GraphSession.charge_full_writeback`); any
-other round derives the (destination, machine) pairs its edges touch.
+other round derives the (destination, machine) pairs its edges touch. A
+dense round whose frontier is every vertex (and whose filter, if any, keeps
+every edge) reads read-only views of the ingested edge arrays
+(`GraphSession.full_edges`) instead of gathering them, and charges its edge
+compute from the ingest-time edges per machine
+(`GraphSession.charge_full_work`); sparse rounds, partial frontiers and
+filters that drop edges gather as before.
 
 Hot-vertex replication (`replicate=`, session-owned, cost-model only): the
 session's `HotChunkReplicator` learns per-round vertex demand and keeps the
@@ -167,17 +173,28 @@ def dist_edge_map(
             mode = "sparse" if (sum_deg + idx.size) < threshold_frac * (g.m + g.n) else "dense"
 
         # ---- gather active edges ------------------------------------------
-        if mode == "sparse":
-            flat, _ = _expand_csr(og.out_indptr, idx)
-            edge_ids = og.out_edges[flat]
+        # a dense round over every vertex reads the ingested edge arrays
+        # (the same edges in the same order a gather would build)
+        full = mode != "sparse" and len(U) == og.n and U.mask.all()
+        if full:
+            edge_ids, s, d, w = sess.full_edges
         else:
-            edge_ids = np.flatnonzero(U.mask[g.src])
-        s, d = g.src[edge_ids], g.dst[edge_ids]
-        w = g.weights[edge_ids] if g.weights is not None else np.ones(edge_ids.size)
+            if mode == "sparse":
+                flat, _ = _expand_csr(og.out_indptr, idx)
+                edge_ids = og.out_edges[flat]
+            else:
+                edge_ids = np.flatnonzero(U.mask[g.src])
+            s, d = g.src[edge_ids], g.dst[edge_ids]
+            w = (g.weights[edge_ids] if g.weights is not None
+                 else np.ones(edge_ids.size))
 
         if filter_dst is not None and edge_ids.size:
-            keep = filter_dst(d)
-            edge_ids, s, d, w = edge_ids[keep], s[keep], d[keep], w[keep]
+            keep = np.asarray(filter_dst(d))
+            if not (full and keep.dtype == bool and keep.all()):
+                full = False
+                edge_ids, s, d, w = edge_ids[keep], s[keep], d[keep], w[keep]
+        if full:
+            sess.full_gather_reuses += 1
 
     cost = CostAccumulator(og.P) if account else None
     if cost is not None:
@@ -237,8 +254,11 @@ def dist_edge_map(
             # constant instead of the work-efficient segmented combine — the
             # 2–5.7× band Table 4 measures. Numerics are unaffected.
             if cost is not None:
-                cost.work(og.edge_machine[edge_ids],
-                          1.0 if fast_local else 3.0)
+                units = 1.0 if fast_local else 3.0
+                if full:
+                    sess.charge_full_work(cost, units)
+                else:
+                    cost.work(og.edge_machine[edge_ids], units)
         # per-destination ⊗-combine through the session's execution backend
         # (numpy oracle, or the jitted segment scatter of core/jaxexec.py)
         with span("edgemap.combine"):

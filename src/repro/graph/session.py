@@ -15,7 +15,10 @@ per-call state:
   * A round whose active edges are all the graph's edges (every PageRank
     round) climbs every ingest-time destination tree once, so its write-back
     bill is the same every such round: the session charges it once and adds
-    it thereafter (`charge_full_writeback`).
+    it thereafter (`charge_full_writeback`). Such a round's edge set is
+    the ingested edge set, in ingest order, so it reads read-only views of
+    the graph's edge arrays (`full_edges`) and its compute bill, which is
+    the same every such round too (`charge_full_work`).
 
 Algorithms construct one session per run (`GraphSession(og, **opts)`) and
 call `session.edge_map(...)` per round; calling `dist_edge_map` directly
@@ -200,6 +203,9 @@ class GraphSession:
         # destination trees (charge_full_writeback)
         self.dst_tree_reuses = 0
         self._full_writeback = {}  # dedup -> (sent, recv, rounds)
+        # rounds whose frontier was every vertex and whose edges were the
+        # ingested arrays (full_edges), not a per-round gather
+        self.full_gather_reuses = 0
 
     # ------------------------------------------------------------------
     @property
@@ -257,6 +263,37 @@ class GraphSession:
         cost.add_comm(sent, recv)
         cost.tick(rounds)
         self.dst_tree_reuses += 1
+
+    @functools.cached_property
+    def full_edges(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray,
+                                  np.ndarray]:
+        """(edge ids, sources, destinations, weights) of a round whose
+        active edges are all the graph's edges, in ingest order: the same
+        arrays a dense gather over a full frontier builds, as read-only
+        views of the graph's arrays (ids `arange(m)`, weights all ones on an
+        unweighted graph, both built once). Read-only, so a callback that
+        writes into its arguments raises instead of changing the graph."""
+        g = self.og.graph
+        w = g.weights if g.weights is not None else np.ones(g.m)
+        out = []
+        for a in (np.arange(g.m, dtype=np.int64), g.src, g.dst, w):
+            v = a.view()
+            v.flags.writeable = False
+            out.append(v)
+        return tuple(out)
+
+    @functools.cached_property
+    def _edges_per_machine(self) -> np.ndarray:
+        return self.og.edges_per_machine().astype(np.float64)
+
+    def charge_full_work(self, cost: CostAccumulator, units: float) -> None:
+        """Charge the edge compute of a round whose active edges are all
+        the graph's edges: `units` per edge on the machine storing it, so
+        each machine's bill is its ingest-time edge count times `units`.
+        With whole `units` (1 or 3), every total is a whole number far below
+        2**53, so the float64 sums come out the same as charging the edges
+        one by one."""
+        cost.work(np.arange(self.og.P), self._edges_per_machine * units)
 
     def ensure_replicator(self, spec=True):
         """Create the session's replicator on first use (for

@@ -114,6 +114,7 @@ def test_bfs_on_a_path_never_reads_the_trees():
     dist, info = bfs(og, source=0, session=sess)
     assert info.rounds > 10 and dist[n - 1] == n - 1
     assert sess.dst_tree_reuses == 0
+    assert sess.full_gather_reuses == 0
 
 
 def test_partial_dense_frontier_keeps_the_per_round_derivation():
