@@ -31,6 +31,8 @@ across backends, because every quantity the cost model consumes (execution
 sites, written-key sets, message widths) is computed on the host by the same
 code regardless of backend — only the floating-point *values* differ, within
 tolerance. `tests/test_backend_parity.py` pins this for all four engines.
+Backends charge nothing: `apply_writes` returns the sorted unique keys it
+wrote, and the engine charges their ⊙-apply.
 
 Lambdas under the jax backend are traced with jnp arrays; a lambda that is
 not traceable (calls numpy on its inputs, data-dependent control flow) is
@@ -134,8 +136,11 @@ class NumpyBackend:
         return execution.execute(tasks, store, f)
 
     # -- phase 4 -----------------------------------------------------------
-    def apply_writes(self, tasks, store, updates, merge: MergeOp, cost) -> None:
-        execution.apply_writes(tasks, store, updates, merge, cost)
+    def apply_writes(self, tasks, store, updates, merge: MergeOp
+                     ) -> np.ndarray:
+        """⊗-combine and ⊙-apply the update rows into `store`; returns the
+        sorted unique keys written (empty when none were)."""
+        return execution.apply_writes(tasks, store, updates, merge)
 
     # -- phase 1 -----------------------------------------------------------
     def key_counts(self, keys: np.ndarray, num_keys: int, weights=None
@@ -565,18 +570,17 @@ class JaxBackend(NumpyBackend):
         return stash, updates
 
     # -- phase 4 ⊙ ----------------------------------------------------------
-    def apply_writes(self, tasks, store, updates, merge: MergeOp, cost) -> None:
+    def apply_writes(self, tasks, store, updates, merge: MergeOp
+                     ) -> np.ndarray:
         if updates is None:
-            return
+            return np.empty(0, dtype=np.int64)
         stash, updates = self._take_stash(tasks, updates, merge)
         if stash is None:
             self._flush_if_deferred(store)
-            execution.apply_writes(tasks, store, updates, merge, cost)
-            return
+            return execution.apply_writes(tasks, store, updates, merge)
         _, _, _, uniq, combined_dev, _, dv = stash
         if uniq.size == 0:
-            return
-        cost.work(store.home[uniq], 1.0)
+            return uniq
         # device-side ⊙-apply (no full re-upload next stage); padding keys
         # are ascending out-of-range rows, so the scatter sees sorted unique
         # indices and is dropped past num_keys
@@ -596,7 +600,7 @@ class JaxBackend(NumpyBackend):
             self._remember_values(store, new_dv)
             self._plan_written.append(uniq)
             self._plan_dirty = True
-            return
+            return uniq
         # authoritative host apply (store dtype), exactly the oracle's ⊙
         with span("backend.writeback"):
             combined = self._fetch(combined_dev)[:uniq.size].astype(
@@ -604,6 +608,7 @@ class JaxBackend(NumpyBackend):
             self.host_syncs += 1
             store.write_rows(uniq, merge.apply(store.values[uniq], combined))
         self._remember_values(store, new_dv)
+        return uniq
 
     # -- phase 1 ------------------------------------------------------------
     def key_counts(self, keys: np.ndarray, num_keys: int, weights=None
@@ -784,18 +789,17 @@ class SpmdBackend(JaxBackend):
         return host
 
     # -- phase 4 ⊙ (owner shards already applied; host copy catches up) ------
-    def apply_writes(self, tasks, store, updates, merge: MergeOp, cost) -> None:
+    def apply_writes(self, tasks, store, updates, merge: MergeOp
+                     ) -> np.ndarray:
         if updates is None:
-            return
+            return np.empty(0, dtype=np.int64)
         stash, updates = self._take_stash(tasks, updates, merge)
         if stash is None:
             self._flush_if_deferred(store)
-            execution.apply_writes(tasks, store, updates, merge, cost)
-            return
+            return execution.apply_writes(tasks, store, updates, merge)
         _, _, _, uniq, new_slabs, _, rep_arrays, replicas = stash
         if uniq.size == 0:
-            return
-        cost.work(store.home[uniq], 1.0)
+            return uniq
         # the owner shards already ⊙-applied to their slabs inside the
         # stage program; the authoritative host copy catches up with the
         # written rows, each shard reading its own
@@ -807,6 +811,7 @@ class SpmdBackend(JaxBackend):
         if rep_arrays is not None and replicas is not None:
             self._sx._pin_replicas(store, replicas, self._np_dtype,
                                    rep_arrays)
+        return uniq
 
 
 def make_backend(spec, *, kernel_backend: Optional[str] = None

@@ -28,6 +28,12 @@ baseline — while *cost* (per-machine words sent/received, work executed,
 BSP rounds) is accounted by faithfully walking the forest/meta-task
 structures. This separates what the paper proves (Theorem 1 is about cost
 and balance) from what a pure re-implementation could only sample.
+
+Each engine charges through one set of routines — route (Phases 1–2, up to
+each task's execution site), execute, write-back — that `run_stage` calls
+around the backend's numerics and `estimate_cost` calls without the lambda.
+The backends charge nothing: `apply_writes` returns the keys it wrote, and
+the engine charges their ⊙-apply (`charge_apply`).
 """
 from __future__ import annotations
 
@@ -90,6 +96,60 @@ class _Stores:
     def __len__(self) -> int:
         return len(self.machine)
 
+    def edges(self, home: np.ndarray):
+        """(each store's machine, the machine one step toward the tree root
+        — its parent store's, or the chunk's home — and the tree's depth in
+        BSP rounds)."""
+        machine = np.array(self.machine, dtype=np.int64)
+        parent = np.array(self.parent, dtype=np.int64)
+        up = np.where(parent >= 0, machine[np.maximum(parent, 0)],
+                      home[np.array(self.key, dtype=np.int64)])
+        return machine, up, max(self.level) + 1
+
+
+@dataclasses.dataclass
+class _Route:
+    """Where Phase 1 put a stage: each task's execution site, each pair's
+    co-location site, the replica-local pairs, the parked meta-tasks, and
+    the per-key refcounts observed at the tree roots."""
+
+    exec_site: np.ndarray
+    pair_site: np.ndarray
+    pair_local: np.ndarray
+    stores: _Stores
+    root_key: np.ndarray
+    root_cnt: np.ndarray
+
+
+# -- charge routines every engine composes, in run_stage and estimate_cost --
+def charge_lambda_work(cost, tasks, site, per_task, per_pair) -> None:
+    """The lambda's compute at the execution sites: `per_task` units a
+    task, plus `per_pair` per (task, requested-key) pair."""
+    cost.work(site, per_task)
+    if per_pair and tasks.nnz:
+        cost.work(site[tasks.pair_task], per_pair)
+
+
+def charge_apply(cost, store, written) -> None:
+    """The ⊙-apply: one work unit at the home of each written key
+    (`written` as `apply_writes` returns it; None means nothing written)."""
+    if written is not None:
+        cost.work(store.home[written], 1.0)
+
+
+def result_width(results, wanted: bool):
+    """Words per result row sent back to the origins, or None if none are."""
+    if not wanted or results is None:
+        return None
+    return results.shape[1] if results.ndim > 1 else 1
+
+
+def update_width(updates) -> int:
+    """Words per update row as the baselines bill it. A 1-D `(n,)` array
+    reads as n words wide (TD-Orch reads it as one)."""
+    u = np.atleast_2d(np.asarray(updates))
+    return u.shape[1] if u.shape[0] != u.size else 1
+
 
 @register_engine("tdorch")
 class TDOrchEngine:
@@ -140,41 +200,9 @@ class TDOrchEngine:
         stealer=None,
     ) -> OrchestrationResult:
         merge = get_merge_op(write_back)
-        P, forest = self.P, self.forest
-        sigma = self.sigma_override or tasks.ctx_words
-        B = store.chunk_words
-        # theory-guided C = Θ(B/σ), §3.2/§3.5; ≥2 so a lone duplicate never parks
-        C = self.C_override or max(2, int(math.ceil(B / max(sigma, 1))))
-
-        cost = CostAccumulator(P)
-        arity = tasks.arity
-        has_read = arity > 0
-        # each (task, key) pair gets a co-location site; tasks with no read
-        # execute in place, the rest where their primary pair lands
-        pair_site = tasks.origin[tasks.pair_task]
-        # pairs whose chunk is replicated at the requesting machine are
-        # satisfied by the session's hot-chunk directory: they never climb
-        # the forest, and their task (if primary) executes in place
-        if replicas is not None and replicas.hot_ids.size and tasks.nnz:
-            pair_local = replicas.holds(tasks.read_indices, pair_site)
-        else:
-            pair_local = np.zeros(tasks.nnz, dtype=bool)
-
-        stores = _Stores()
-        root_rows_key: np.ndarray = np.empty(0, dtype=np.int64)
-        root_rows_cnt: np.ndarray = np.empty(0, dtype=np.int64)
-
-        # ---------------- Phase 1: contention detection --------------------
+        cost = CostAccumulator(self.P)
         with span("phase1"):
-            cost.begin("phase1_contention_detection")
-            if tasks.nnz:
-                pair_site, root_rows_key, root_rows_cnt = self._phase1(
-                    tasks, store, cost, stores, pair_site, sigma, C,
-                    climb=~pair_local,
-                )
-            cost.end()
-            exec_site = tasks.origin.copy()
-            exec_site[has_read] = pair_site[tasks.read_indptr[:-1][has_read]]
+            rt = self._climb(tasks, store, cost, replicas, tasks.ctx_words)
 
         # ---------------- Phase-3 work stealing (core/elasticity.py) -------
         # Rebalance exec-site assignment BEFORE Phase 2, so a stolen task's
@@ -182,27 +210,20 @@ class TDOrchEngine:
         # primaries stay put — stealing them would forfeit the local read.
         if stealer is not None:
             cost.begin("phase3_steal")
+            has_read = tasks.arity > 0
             prim_local = np.zeros(tasks.n, dtype=bool)
-            if pair_local.any():
+            if rt.pair_local.any():
                 prim_local[has_read] = \
-                    pair_local[tasks.read_indptr[:-1][has_read]]
-            exec_site = stealer.steal(tasks, exec_site, cost,
-                                      value_width=store.value_width,
-                                      eligible=~prim_local)
+                    rt.pair_local[tasks.read_indptr[:-1][has_read]]
+            rt.exec_site = stealer.steal(tasks, rt.exec_site, cost,
+                                         value_width=store.value_width,
+                                         eligible=~prim_local)
             cost.end()
 
-        # ---------------- Phase 2: push-pull co-location -------------------
         with span("phase2"):
-            cost.begin("phase2_push_pull")
-            self._phase2_pull(store, cost, stores, B)
-            self._phase2_replica_local(tasks, store, cost, pair_local)
-            self._phase2_secondary(tasks, store, cost, pair_site, exec_site,
-                                   replicas)
-            cost.end()
+            self._colocate(tasks, store, cost, rt, replicas)
 
-        # ---------------- Phase 3: execution -------------------------------
         with span("phase3"):
-            cost.begin("phase3_execute")
             # want_result lets a device backend skip materializing per-task
             # results the caller never asked for (a StagePlan round's only
             # host traffic is then the write-back / flush path); exec_site/
@@ -210,105 +231,114 @@ class TDOrchEngine:
             # where the cost model just charged it
             out = self.backend.execute(tasks, store, f, merge,
                                        want_result=return_results,
-                                       exec_site=exec_site, replicas=replicas)
-            updates = out.get("update")
+                                       exec_site=rt.exec_site,
+                                       replicas=replicas)
             results = out.get("result")
-            cost.work(exec_site, self.work_per_task)
-            if self.work_per_pair and tasks.nnz:
-                cost.work(exec_site[tasks.pair_task], self.work_per_pair)
-            if return_results and results is not None:
-                w_r = results.shape[1] if results.ndim > 1 else 1
-                cost.send(exec_site, tasks.origin, w_r + 1)
-                cost.tick()
-            cost.end()
+            self._charge_execute(cost, tasks, rt.exec_site,
+                                 result_width(results, return_results))
 
-        # ---------------- Phase 4: write-backs -----------------------------
         with span("phase4"):
-            cost.begin("phase4_write_back")
+            updates = out.get("update")
+            w_u = written = None
             if updates is not None:
-                self._phase4(tasks, store, cost, stores, exec_site, updates,
-                             merge, replicas)
-            cost.end()
+                updates = np.atleast_2d(np.asarray(updates))
+                if updates.shape[0] != tasks.n:
+                    updates = updates.T
+                w_u = updates.shape[1]
+                if (tasks.write_keys >= 0).any():
+                    written = self.backend.apply_writes(tasks, store, updates,
+                                                        merge)
+            self._charge_write_back(cost, tasks, store, rt, w_u, written,
+                                    replicas)
 
         refcount = {
-            int(k): int(c) for k, c in zip(root_rows_key, root_rows_cnt) if c > 0
+            int(k): int(c) for k, c in zip(rt.root_key, rt.root_cnt) if c > 0
         }
         # replica-local pairs are observed at their origin machine — the
         # leaf-level half of contention detection — so the demand histogram
         # keeps seeing the full per-chunk request stream
-        if pair_local.any():
+        if rt.pair_local.any():
             lk, lc = self.backend.key_counts(
-                tasks.read_indices[pair_local], store.num_keys)
+                tasks.read_indices[rt.pair_local], store.num_keys)
             for k, c in zip(lk, lc):
                 refcount[int(k)] = refcount.get(int(k), 0) + int(c)
         return OrchestrationResult(
             results=results,
             report=cost.totals(),
-            exec_site=exec_site,
+            exec_site=rt.exec_site,
             refcount=refcount,
         )
 
     # ------------------------------------------------------------------
-    def estimate_cost(self, histogram, layout):
-        """Analytic cost estimate for running `layout`'s stage on THIS engine
-        (the `engine="auto"` policy contract, core/policy.py).
-
-        Replays the exact Phase 1–4 charging paths above — the same forest
-        climb, meta-task parking, pull broadcast, and reverse-tree write-back
-        — against a scratch `CostAccumulator`, without executing the lambda.
-        The estimate is therefore bit-identical to the realized stage report
-        whenever the layout's assumptions hold: the lambda returns
-        `layout.update_width`-wide updates for every declared write key and
-        `layout.result_width`-wide results when `return_results` is set, and
-        no Phase-3 work stealing intervenes. `histogram` (the Phase-1 demand
-        histogram) is accepted per the estimator contract; TD-Orch's climb
-        is replayed from the pair stream itself, which the histogram is a
-        projection of."""
+    def estimate_cost(self, layout):
+        """The bill of running `layout`'s stage on this engine, without the
+        lambda (the `engine="auto"` contract, core/policy.py): the same
+        Phase 1–4 charge routines `run_stage` calls, with the layout's
+        widths and write keys in place of the executed ones. Equal to the
+        realized report whenever those assumptions hold and no Phase-3
+        work stealing intervenes."""
         from .policy import PhaseCostEstimate  # local: policy imports engines
         tasks, store, replicas = layout.tasks, layout.store, layout.replicas
-        sigma = self.sigma_override or layout.sigma
-        B = store.chunk_words
-        C = self.C_override or max(2, int(math.ceil(B / max(sigma, 1))))
         cost = CostAccumulator(self.P)
-        has_read = tasks.arity > 0
+        rt = self._climb(tasks, store, cost, replicas, layout.sigma)
+        self._colocate(tasks, store, cost, rt, replicas)
+        self._charge_execute(cost, tasks, rt.exec_site, layout.returned_width)
+        self._charge_write_back(cost, tasks, store, rt, *layout.writes,
+                                replicas)
+        return PhaseCostEstimate("tdorch", cost.totals())
+
+    # ------------------------------------------------------------------
+    def _climb(self, tasks, store, cost, replicas, sigma) -> _Route:
+        """Phase 1: every (task, key) pair not served by a local replica
+        climbs the forest; each task executes where its primary pair
+        landed, or at its origin when it reads nothing."""
+        sigma = self.sigma_override or sigma
+        # theory-guided C = Θ(B/σ), §3.2/§3.5; ≥2 so a lone duplicate never parks
+        C = self.C_override or max(
+            2, int(math.ceil(store.chunk_words / max(sigma, 1))))
+        # each (task, key) pair gets a co-location site; pairs whose chunk
+        # is replicated at the requesting machine are satisfied by the
+        # session's hot-chunk directory: they never climb the forest, and
+        # their task (if primary) executes in place
         pair_site = tasks.origin[tasks.pair_task]
         if replicas is not None and replicas.hot_ids.size and tasks.nnz:
             pair_local = replicas.holds(tasks.read_indices, pair_site)
         else:
             pair_local = np.zeros(tasks.nnz, dtype=bool)
         stores = _Stores()
+        root_key = root_cnt = np.empty(0, dtype=np.int64)
         cost.begin("phase1_contention_detection")
         if tasks.nnz:
-            pair_site, _, _ = self._phase1(tasks, store, cost, stores,
-                                           pair_site, sigma, C,
-                                           climb=~pair_local)
+            pair_site, root_key, root_cnt = self._phase1(
+                tasks, store, cost, stores, pair_site, sigma, C,
+                climb=~pair_local)
         cost.end()
+        has_read = tasks.arity > 0
         exec_site = tasks.origin.copy()
         exec_site[has_read] = pair_site[tasks.read_indptr[:-1][has_read]]
+        return _Route(exec_site, pair_site, pair_local, stores, root_key,
+                      root_cnt)
+
+    def _colocate(self, tasks, store, cost, rt, replicas) -> None:
+        """Phase 2: pull broadcasts to the parked meta-tasks, replica-local
+        primary reads, and secondary values forwarded to `rt.exec_site`."""
         cost.begin("phase2_push_pull")
-        self._phase2_pull(store, cost, stores, B)
-        self._phase2_replica_local(tasks, store, cost, pair_local)
-        self._phase2_secondary(tasks, store, cost, pair_site, exec_site,
+        self._phase2_pull(store, cost, rt.stores)
+        self._phase2_replica_local(tasks, store, cost, rt.pair_local)
+        self._phase2_secondary(tasks, store, cost, rt.pair_site, rt.exec_site,
                                replicas)
         cost.end()
+
+    def _charge_execute(self, cost, tasks, exec_site, w_r) -> None:
+        """Phase 3: the lambda's work at the execution sites, and `w_r`-word
+        result rows back to each task's origin (None: no results)."""
         cost.begin("phase3_execute")
-        cost.work(exec_site, self.work_per_task)
-        if self.work_per_pair and tasks.nnz:
-            cost.work(exec_site[tasks.pair_task], self.work_per_pair)
-        if layout.return_results:
-            cost.send(exec_site, tasks.origin, layout.result_width + 1)
+        charge_lambda_work(cost, tasks, exec_site, self.work_per_task,
+                           self.work_per_pair)
+        if w_r is not None:
+            cost.send(exec_site, tasks.origin, w_r + 1)
             cost.tick()
         cost.end()
-        cost.begin("phase4_write_back")
-        if layout.assume_updates:
-            wrote = self._phase4_charge(tasks, store, cost, stores, exec_site,
-                                        layout.update_width, replicas)
-            if wrote:
-                # the authoritative ⊙-apply charge (execution.apply_writes)
-                uniq = np.unique(tasks.write_keys[tasks.write_keys >= 0])
-                cost.work(store.home[uniq], 1.0)
-        cost.end()
-        return PhaseCostEstimate("tdorch", cost.totals())
 
     # ------------------------------------------------------------------
     def _phase1(self, tasks, store, cost, stores, pair_site, sigma, C,
@@ -451,17 +481,13 @@ class TDOrchEngine:
         return tbl
 
     # ------------------------------------------------------------------
-    def _phase2_pull(self, store, cost, stores, B):
+    def _phase2_pull(self, store, cost, stores):
         """Broadcast chunk copies down the meta-task tree (§3.3 "Pull")."""
         if len(stores) == 0:
             return
-        machine = np.array(stores.machine, dtype=np.int64)
-        key = np.array(stores.key, dtype=np.int64)
-        parent = np.array(stores.parent, dtype=np.int64)
-        src = np.where(parent >= 0, machine[np.maximum(parent, 0)], store.home[key])
-        cost.send(src, machine, B + 1)
-        levels = np.array(stores.level, dtype=np.int64)
-        cost.tick(int(levels.max(initial=0)) + 1)
+        machine, up, depth = stores.edges(store.home)
+        cost.send(up, machine, store.chunk_words + 1)
+        cost.tick(depth)
         cost.work(machine, 1.0)
 
     # ------------------------------------------------------------------
@@ -512,61 +538,39 @@ class TDOrchEngine:
         cost.tick()
 
     # ------------------------------------------------------------------
-    def _phase4(self, tasks, store, cost, stores, exec_site, updates, merge,
-                replicas=None):
-        """Merge-able write-backs (§3.4). In-tree writes climb the reverse
-        meta-task tree; cross-key writes ride the destination forest.
-        Written chunks that are replicated get their ⊗-combined update
-        write-through-propagated from home to every other holder."""
-        updates = np.atleast_2d(np.asarray(updates))
-        if updates.shape[0] != tasks.n:
-            updates = updates.T
-        if not self._phase4_charge(tasks, store, cost, stores, exec_site,
-                                   updates.shape[1], replicas):
-            return
-        # --- numeric application (single authoritative ⊙ per chunk, shared)
-        self.backend.apply_writes(tasks, store, updates, merge, cost)
-
-    # ------------------------------------------------------------------
-    def _phase4_charge(self, tasks, store, cost, stores, exec_site, w_u,
-                       replicas=None) -> bool:
-        """The Phase-4 charging paths, without the numeric ⊙-apply — shared
-        verbatim between `run_stage` (which then applies the updates) and
-        `estimate_cost` (which only needs the bill). Returns whether any
-        write happened."""
+    def _charge_write_back(self, cost, tasks, store, rt, w_u, written,
+                           replicas=None) -> None:
+        """Phase 4: merge-able write-backs of `w_u`-word updates (None: the
+        lambda returned none) and the ⊙-apply of the `written` keys (§3.4).
+        In-tree writes climb the reverse meta-task tree; cross-key writes
+        ride the destination forest. Written chunks that are replicated get
+        their ⊗-combined update write-through-propagated from home to every
+        other holder."""
+        cost.begin("phase4_write_back")
         writes = tasks.write_keys >= 0
-        if not writes.any():
-            return False
-
-        # writes to the task's primary key climb its reverse meta-task tree;
-        # everything else (cross-key, secondary-key) rides the dest forest
-        in_tree = writes & (tasks.write_keys == tasks.primary_read)
-        cross = writes & ~in_tree
-
-        # --- reverse meta-task tree: one ⊗-combined message per store edge
-        if len(stores) > 0:
-            machine = np.array(stores.machine, dtype=np.int64)
-            key = np.array(stores.key, dtype=np.int64)
-            parent = np.array(stores.parent, dtype=np.int64)
-            dst = np.where(parent >= 0, machine[np.maximum(parent, 0)], store.home[key])
-            cost.send(machine, dst, w_u + 1)
-            n_members = np.array(stores.n_members, dtype=np.float64)
-            cost.work(machine, n_members)  # local ⊗ combining
-            levels = np.array(stores.level, dtype=np.int64)
-            cost.tick(int(levels.max(initial=0)) + 1)
-        # root-resident tasks write locally (no comm)
-
-        # --- cross-key writes: climb the destination forest, ⊗ en route
-        if cross.any():
-            self._forest_scatter_reduce(
-                tasks.write_keys[cross], exec_site[cross], store, cost, w_u
-            )
-
-        # --- replica maintenance: home → holders, one combined row each
-        if replicas is not None:
-            charge_write_through(cost, store.home, replicas,
-                                 tasks.write_keys[writes], w_u)
-        return True
+        if w_u is not None and writes.any():
+            # writes to the task's primary key climb its reverse meta-task
+            # tree; everything else (cross-key, secondary-key) rides the
+            # dest forest
+            cross = writes & (tasks.write_keys != tasks.primary_read)
+            # reverse meta-task tree: one ⊗-combined message per store
+            # edge; root-resident tasks write locally (no comm)
+            if len(rt.stores) > 0:
+                machine, up, depth = rt.stores.edges(store.home)
+                cost.send(machine, up, w_u + 1)
+                cost.work(machine, np.array(rt.stores.n_members,
+                                            dtype=np.float64))  # local ⊗
+                cost.tick(depth)
+            if cross.any():
+                self._forest_scatter_reduce(tasks.write_keys[cross],
+                                            rt.exec_site[cross], store, cost,
+                                            w_u)
+            # replica maintenance: home → holders, one combined row each
+            if replicas is not None:
+                charge_write_through(cost, store.home, replicas,
+                                     tasks.write_keys[writes], w_u)
+            charge_apply(cost, store, written)
+        cost.end()
 
     # ------------------------------------------------------------------
     def _forest_scatter_reduce(self, wkeys, site, store, cost, w_u):

@@ -83,24 +83,18 @@ def execute(tasks: TaskBatch, store: DataStore, f: Callable
 
 
 def apply_writes(tasks: TaskBatch, store: DataStore, updates,
-                 merge: MergeOp, cost) -> None:
-    """The single authoritative ⊗-combine + ⊙-apply pass (shared)."""
-    if updates is None:
-        return
+                 merge: MergeOp) -> np.ndarray:
+    """The single authoritative ⊗-combine + ⊙-apply pass (shared). Returns
+    the sorted unique keys it wrote (empty when it wrote none)."""
+    writes = tasks.write_keys >= 0
+    if updates is None or not writes.any():
+        return np.empty(0, dtype=np.int64)
     updates = np.atleast_2d(np.asarray(updates))
     if updates.shape[0] != tasks.n:
         updates = updates.T
-    writes = tasks.write_keys >= 0
-    if not writes.any():
-        return
     wk = tasks.write_keys[writes]
     uniq, seg = np.unique(wk, return_inverse=True)
     combined = merge.combine_segments(updates[writes], seg, uniq.size,
                                       tasks.priority[writes])
     store.write_rows(uniq, merge.apply(store.values[uniq], combined))
-    cost.work(store.home[uniq], 1.0)
-
-
-def update_width(updates) -> int:
-    u = np.atleast_2d(np.asarray(updates))
-    return u.shape[1] if u.shape[0] != u.size else 1
+    return uniq

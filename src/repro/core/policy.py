@@ -8,13 +8,14 @@ for the reproduction. Until now the caller picked one of the four registered
 engines per session; `engine="auto"` makes the session pick per stage, from
 the same word-counting rules the engines already charge:
 
-  * every engine exposes `estimate_cost(histogram, layout) ->
-    PhaseCostEstimate` — an analytic replay of its own charging paths
-    against the stage's `StageLayout` (task batch, store placement, replica
-    directory, result/update widths). The estimate is bit-identical to the
-    realized stage report whenever the layout's documented assumptions hold
-    (lambda returns `update_width`-wide updates for every declared write
-    key, `result_width`-wide results when requested, no work stealing);
+  * every engine exposes `estimate_cost(layout) -> PhaseCostEstimate`: its
+    own charge routines — the ones `run_stage` calls — run without the
+    lambda over the stage's `StageLayout` (task batch, store placement,
+    replica directory, result/update widths). The estimate is bit-identical
+    to the realized stage report whenever the layout's documented
+    assumptions hold (lambda returns `update_width`-wide updates for every
+    declared write key, `result_width`-wide results when requested, no work
+    stealing);
   * `StagePolicy` picks the argmin engine under a configurable objective
     (total words by default; a BSP `max_comm + L·rounds` objective for
     latency-bound stages), with hysteresis so fixpoint loops don't thrash
@@ -25,16 +26,16 @@ the same word-counting rules the engines already charge:
     `run_plan` rounds, `DistributedHashTable`, `serve.Frontend`, the
     paramserve tier — gets the adaptive loop by spelling `engine="auto"`.
 
-Decisions are deterministic and backend-independent: the demand histogram
-is a plain `np.bincount` of the batch's requested keys, and the only
-backend call the estimators make (`argsort_stable`, for sort's run
-placement) is parity-pinned across numpy/jax/jax_spmd. Each decision is
-recorded on the session's `SessionReport.policy_decisions` (chosen engine,
-per-candidate predicted bills, predicted vs. realized words), and the cost
-of *deciding* — per-machine demand sketches to a coordinator plus the
-decision broadcast — is charged under the dedicated `policy` phase
-(`cost.POLICY_PHASE`), so parity tests can compare an auto stage against
-the chosen fixed engine with `assert_cost_parity(..., ignore=("policy",))`.
+Decisions are deterministic and backend-independent: the estimators read
+only the layout, and the only backend call they make (`argsort_stable`, for
+sort's run placement) is parity-pinned across numpy/jax/jax_spmd. Each
+decision is recorded on the session's `SessionReport.policy_decisions`
+(chosen engine, per-candidate predicted bills, predicted vs. realized
+words), and the cost of *deciding* — per-machine demand sketches to a
+coordinator plus the decision broadcast — is charged under the dedicated
+`policy` phase (`cost.POLICY_PHASE`), so parity tests can compare an auto
+stage against the chosen fixed engine with `assert_cost_parity(...,
+ignore=("policy",))`.
 """
 from __future__ import annotations
 
@@ -63,9 +64,9 @@ __all__ = [
 class StageLayout:
     """The cost-relevant projection of one stage, handed to estimators.
 
-    Holds *references* to the live batch/store/directory (estimators replay
-    charging formulas against them; nothing is copied or mutated) plus the
-    width assumptions that stand in for the not-yet-executed lambda:
+    Holds *references* to the live batch/store/directory (estimators charge
+    against them; nothing is copied or mutated) plus the width assumptions
+    that stand in for the not-yet-executed lambda:
 
     sigma           context words per task (σ) — `tasks.ctx_words`.
     update_width    words per ⊗-combined update row the lambda will return
@@ -104,11 +105,26 @@ class StageLayout:
                                 if assume_updates is None else assume_updates),
         )
 
+    @property
+    def returned_width(self) -> Optional[int]:
+        """Words per result row sent back, or None when none are."""
+        return self.result_width if self.return_results else None
+
+    @property
+    def writes(self) -> Tuple[Optional[int], Optional[np.ndarray]]:
+        """(update width, written keys) as the engine's write-back charge
+        takes them after `apply_writes`, or (None, None) when the lambda
+        is assumed to return no updates."""
+        if not self.assume_updates:
+            return None, None
+        wk = self.tasks.write_keys
+        return self.update_width, np.unique(wk[wk >= 0])
+
 
 @dataclasses.dataclass(frozen=True)
 class PhaseCostEstimate:
     """One engine's predicted bill for one stage: a full per-phase
-    `StageReport` produced by replaying the engine's charging paths, so a
+    `StageReport` produced by the engine's own charge routines, so a
     conformance test can pin prediction against realization with
     `assert_cost_parity` — not just compare scalars."""
 
@@ -289,7 +305,7 @@ def decision_phase(P: int, active_machines: np.ndarray,
 @register_engine("auto")
 class AutoEngine:
     """The adaptive orchestrator: per stage, estimate every candidate
-    engine's bill from the demand histogram and the stage layout, pick the
+    engine's bill from the stage layout, pick the
     argmin (with hysteresis), charge the decision under the `policy` phase,
     and delegate the stage to the winner.
 
@@ -331,14 +347,7 @@ class AutoEngine:
                   return_results=False, replicas=None, stealer=None):
         layout = StageLayout.capture(tasks, store, replicas=replicas,
                                      return_results=return_results)
-        # Phase-1 demand histogram, decision input — plain numpy bincount so
-        # the decision is bit-reproducible across runs and backends
-        if tasks.nnz:
-            histogram = np.bincount(tasks.read_indices,
-                                    minlength=store.num_keys)
-        else:
-            histogram = np.zeros(store.num_keys, dtype=np.int64)
-        estimates = {nm: eng.estimate_cost(histogram, layout)
+        estimates = {nm: eng.estimate_cost(layout)
                      for nm, eng in self.engines.items()}
         decision = self.policy.choose(estimates)
         policy_report = decision_phase(

@@ -288,7 +288,7 @@ def charge_write_through(cost: CostAccumulator, home: np.ndarray,
     held = replicas.holders[slot].ravel()
     src = np.repeat(np.asarray(home, dtype=np.int64)[keys], P)[held]
     dst = np.tile(np.arange(P, dtype=np.int64), keys.size)[held]
-    # home's own authoritative ⊙ is charged by apply_writes — bill only the
+    # home's own authoritative ⊙ is the engine's charge_apply — bill only the
     # genuinely remote holders (whose sends are the non-self rows anyway)
     remote = src != dst
     cost.send(src[remote], dst[remote], words + 1)

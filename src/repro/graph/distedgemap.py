@@ -53,12 +53,48 @@ from .session import VALUE_WORDS, TreeCharger, _expand_csr, session_for
 from .vertex_subset import DistVertexSubset
 
 
+def _charge_propagation(cost, og, sess, idx, mode, replicas, dedup) -> None:
+    """Charge a `mode` round's source-value propagation from frontier `idx`
+    — what the realized round and the mode estimates both bill."""
+    if not idx.size:
+        return
+    # replicated sources: every machine holding their out-edges already
+    # has the value — a machine-local read, no tree/broadcast traffic
+    live = idx
+    if replicas is not None and mode in ("sparse", "dense"):
+        # a vertex counts as replicated only when EVERY machine holds it
+        # (conservative under a partial holders bitmap: any gap falls
+        # back to the full tree broadcast)
+        slot = replicas.lookup[idx]
+        hot = slot >= 0
+        hot[hot] = replicas.holders[slot[hot]].all(axis=1)
+        if hot.any() and (dedup or mode == "sparse"):
+            flat_h, _ = _expand_csr(og.src_grp_indptr, idx[hot])
+            cost.local(og.src_grp_machines[flat_h], VALUE_WORDS)
+            live = idx[~hot]
+    if mode == "sparse":
+        h = (sess.src_charger.charge(cost, live, VALUE_WORDS, upward=False)
+             if live.size else 0)
+        cost.tick(max(h, 1))
+        return
+    if dedup:
+        # T1 destination-aware broadcast: value -> only machines holding
+        # that vertex's out-edges, one copy each
+        if live.size:
+            sess.src_charger.direct_broadcast(cost, live, VALUE_WORDS)
+    else:
+        # naive dense: broadcast every active value to all machines
+        for mch in range(og.P):
+            cost.send(og.vertex_home[idx], np.full(idx.size, mch),
+                      VALUE_WORDS)
+    cost.tick(1)
+
+
 def _estimate_mode_costs(og, sess, idx, replicas, dedup):
-    """Charge both propagation modes' bills against scratch accumulators —
-    the graph-side `estimate_cost` (core/policy.py contract). Exact by
-    construction: the same `TreeCharger.charge`/`direct_broadcast` calls the
-    realized round makes, over the same frontier and replica discount. Only
-    the source-propagation phase is mode-DEPENDENT (edge compute and the
+    """Both propagation modes' bills, each on a scratch accumulator — the
+    graph-side `estimate_cost` (core/policy.py contract), exact because it
+    is the realized round's own `_charge_propagation`. Only the
+    source-propagation phase is mode-DEPENDENT (edge compute and the
     destination-tree write-back cost the same either way), so the argmin
     over these estimates is the argmin over full round bills."""
     from ..core.policy import PhaseCostEstimate
@@ -66,31 +102,7 @@ def _estimate_mode_costs(og, sess, idx, replicas, dedup):
     for mode in ("sparse", "dense"):
         cost = CostAccumulator(og.P)
         cost.begin(f"edgemap_{mode}")
-        if idx.size:
-            live = idx
-            if replicas is not None:
-                slot = replicas.lookup[idx]
-                hot = slot >= 0
-                hot[hot] = replicas.holders[slot[hot]].all(axis=1)
-                if hot.any() and (dedup or mode == "sparse"):
-                    flat_h, _ = _expand_csr(og.src_grp_indptr, idx[hot])
-                    cost.local(og.src_grp_machines[flat_h], VALUE_WORDS)
-                    live = idx[~hot]
-            if mode == "sparse":
-                h = (sess.src_charger.charge(cost, live, VALUE_WORDS,
-                                             upward=False)
-                     if live.size else 0)
-                cost.tick(max(h, 1))
-            else:
-                if dedup:
-                    if live.size:
-                        sess.src_charger.direct_broadcast(cost, live,
-                                                          VALUE_WORDS)
-                else:
-                    for mch in np.arange(og.P, dtype=np.int64):
-                        cost.send(og.vertex_home[idx],
-                                  np.full(idx.size, mch), VALUE_WORDS)
-                cost.tick(1)
+        _charge_propagation(cost, og, sess, idx, mode, replicas, dedup)
         cost.end()
         out[mode] = PhaseCostEstimate(mode, cost.totals())
     return out
@@ -213,38 +225,8 @@ def dist_edge_map(
             cost.send(em, og.vertex_home[d], VALUE_WORDS)
             cost.work(og.vertex_home[d], 1.0)
             cost.tick(2)
-        elif cost is not None and idx.size:
-            # replicated sources: every machine holding their out-edges already
-            # has the value — a machine-local read, no tree/broadcast traffic
-            live = idx
-            if replicas is not None and mode in ("sparse", "dense"):
-                # a vertex counts as replicated only when EVERY machine holds it
-                # (conservative under a partial holders bitmap: any gap falls
-                # back to the full tree broadcast)
-                slot = replicas.lookup[idx]
-                hot = slot >= 0
-                hot[hot] = replicas.holders[slot[hot]].all(axis=1)
-                if hot.any() and (dedup or mode == "sparse"):
-                    flat_h, _ = _expand_csr(og.src_grp_indptr, idx[hot])
-                    cost.local(og.src_grp_machines[flat_h], VALUE_WORDS)
-                    live = idx[~hot]
-            if mode == "sparse":
-                h = (sess.src_charger.charge(cost, live, VALUE_WORDS, upward=False)
-                     if live.size else 0)
-                cost.tick(max(h, 1))
-            else:
-                if dedup:
-                    # T1 destination-aware broadcast: value -> only machines
-                    # holding that vertex's out-edges, one copy each
-                    if live.size:
-                        sess.src_charger.direct_broadcast(cost, live, VALUE_WORDS)
-                else:
-                    # naive dense: broadcast every active value to all machines
-                    allm = np.arange(og.P, dtype=np.int64)
-                    for mch in allm:
-                        cost.send(og.vertex_home[idx], np.full(idx.size, mch),
-                                  VALUE_WORDS)
-                cost.tick(1)
+        elif cost is not None:
+            _charge_propagation(cost, og, sess, idx, mode, replicas, dedup)
 
     # ---- local compute ------------------------------------------------------
     if edge_ids.size:

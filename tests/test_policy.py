@@ -10,7 +10,8 @@ The adaptive loop is pinned four ways:
   * **estimator honesty** — predicted vs. realized words agree exactly for
     conforming lambdas (the estimators' documented tolerance is zero when
     update/result widths match and no stealing intervenes), per-phase via
-    `assert_cost_parity`, not just scalars;
+    `assert_cost_parity`, not just scalars — for the engine auto chose,
+    for each engine on its own, and for each edge-map mode;
   * **bit-reproducibility** — decision sequences are identical across
     repeat runs and across numeric backends (numpy vs. jax), because every
     estimator input is parity-pinned;
@@ -229,7 +230,7 @@ def test_decisions_reproducible_across_runs(workload):
 
 @pytest.mark.parametrize("workload", ["zipf_1.2", "hot_chunk"])
 def test_decisions_reproducible_across_backends(workload):
-    """The decision inputs (bincount histogram, estimator replays,
+    """The decision inputs (the layout, the engines' charge routines,
     parity-pinned argsort_stable) are backend-independent, so the decision
     stream — and with it the whole per-phase cost report — must be
     bit-identical between the numpy oracle and the jitted jax backend."""
@@ -335,15 +336,14 @@ def _drift_fixture():
     batches = _zipf_stream(1.2, stages=1, n=320, seed=41)
     tasks = batches[0]
     layout = StageLayout.capture(tasks, store)
-    histogram = np.bincount(tasks.read_indices, minlength=store.num_keys)
-    return store, tasks, layout, histogram
+    return store, tasks, layout
 
 
 def _print_drift_table():  # regeneration helper, not a test
     from repro.core.registry import make_engine
-    store, tasks, layout, histogram = _drift_fixture()
+    store, tasks, layout = _drift_fixture()
     for name in ENGINES:
-        est = make_engine(name, P).estimate_cost(histogram, layout)
+        est = make_engine(name, P).estimate_cost(layout)
         print(f'    "{name}": {{"total_words": {est.total_words!r}, '
               f'"rounds": {est.rounds!r}, "max_comm": {est.max_comm!r}}},')
 
@@ -351,8 +351,8 @@ def _print_drift_table():  # regeneration helper, not a test
 @pytest.mark.parametrize("engine", ENGINES)
 def test_estimator_drift_pinned(engine):
     from repro.core.registry import make_engine
-    store, tasks, layout, histogram = _drift_fixture()
-    est = make_engine(engine, P).estimate_cost(histogram, layout)
+    store, tasks, layout = _drift_fixture()
+    est = make_engine(engine, P).estimate_cost(layout)
     got = {"total_words": est.total_words, "rounds": est.rounds,
            "max_comm": est.max_comm}
     want = _DRIFT_TABLE[engine]
@@ -363,6 +363,130 @@ def test_estimator_drift_pinned(engine):
         f"estimate_cost must change WITH run_stage (they share the same "
         f"word-counting) — then refresh _DRIFT_TABLE in tests/test_policy.py "
         f"via _print_drift_table().")
+
+
+# ---------------------------------------------------------------------------
+# every engine's estimate is its own run_stage bill, per phase
+# ---------------------------------------------------------------------------
+def _conforming(ctx, vals, mask):
+    """Updates and results `W` words wide, as `StageLayout` assumes."""
+    if vals.ndim == 3:
+        vals = (vals * mask[..., None]).sum(axis=1)
+    return {"update": vals * ctx[:, :1] + ctx[:, 1:2], "result": vals}
+
+
+def _multiget_batch(n=240, seed=17):
+    """Ragged reads of arity 0–4 over Zipf keys; writes to the primary
+    key, to another key, or none; tasks that only write."""
+    rng = np.random.default_rng(seed)
+    perm = rng.permutation(K)
+    lists = [zipf_keys_stationary(int(rng.integers(0, 5)), K, 1.1, rng, perm)
+             for _ in range(n)]
+    wk = np.array([k[0] if len(k) and i % 3 else
+                   (int(rng.integers(0, K)) if i % 4 else -1)
+                   for i, k in enumerate(lists)], dtype=np.int64)
+    return TaskBatch.from_ragged(rng.standard_normal((n, 2)), lists,
+                                 rng.integers(0, P, n), write_keys=wk)
+
+
+def _replica_set(tasks, seed=5):
+    """The batch's eight most requested keys, each held by a random half
+    of the machines."""
+    from repro.core.replication import ReplicaSet
+    rng = np.random.default_rng(seed)
+    counts = np.bincount(tasks.read_indices, minlength=K)
+    hot = np.argsort(-counts, kind="stable")[:8].astype(np.int64)
+    lookup = np.full(K, -1, dtype=np.int64)
+    lookup[hot] = np.arange(hot.size)
+    return ReplicaSet(hot_ids=hot, lookup=lookup,
+                      holders=rng.random((hot.size, P)) < 0.5)
+
+
+@pytest.mark.parametrize("results", [False, True], ids=["noresults",
+                                                        "results"])
+@pytest.mark.parametrize("replicated", [False, True],
+                         ids=["plain", "replicated"])
+@pytest.mark.parametrize("batch", ["arity1", "multiget"])
+@pytest.mark.parametrize("engine", ENGINES)
+def test_estimate_equals_run_stage(engine, batch, replicated, results):
+    """`estimate_cost(layout)` is the engine's own `run_stage` bill, phase
+    by phase, for a lambda that meets the layout's width assumptions."""
+    from repro.core.registry import make_engine
+    tasks = (_zipf_stream(1.2, stages=1, n=320, seed=41)[0]
+             if batch == "arity1" else _multiget_batch())
+    replicas = _replica_set(tasks) if replicated else None
+    store = _store()
+    layout = StageLayout.capture(tasks, store, replicas=replicas,
+                                 return_results=results)
+    est = make_engine(engine, P).estimate_cost(layout)
+    res = make_engine(engine, P).run_stage(tasks, store, _conforming,
+                                           return_results=results,
+                                           replicas=replicas)
+    assert_cost_parity(est.report, res.report)
+
+
+@pytest.mark.parametrize("dedup", [True, False], ids=["dedup", "nodedup"])
+@pytest.mark.parametrize("replicated", [False, True],
+                         ids=["plain", "replicated"])
+@pytest.mark.parametrize("mode", ["sparse", "dense"])
+def test_edge_map_estimate_equals_realized_propagation(monkeypatch, mode,
+                                                       replicated, dedup):
+    """A mode's estimate equals the source-value propagation that a round
+    forced into that mode charges: its phase as it stands when the
+    `edgemap.propagate` span closes."""
+    import contextlib
+    import copy
+
+    from repro.core.cost import CostAccumulator
+    from repro.graph import (DistVertexSubset, GraphSession, barabasi_albert,
+                             ingest)
+    from repro.graph import distedgemap as dem
+
+    og = ingest(barabasi_albert(400, 4, seed=7), P=P)
+    sess = GraphSession(og)
+    U = DistVertexSubset(og.n, indices=np.arange(0, og.n, 3))
+    acc = np.zeros(og.n)
+
+    def f(s, d, w):
+        return w
+
+    def write_back(vs, agg):
+        acc[vs] += agg
+        return np.ones(vs.size, dtype=bool)
+
+    kw = dict(session=sess, merge_value="add", force_mode=mode, dedup=dedup)
+    replicas = None
+    if replicated:
+        rep = sess.ensure_replicator({"num_hot": 16, "refresh": 1000,
+                                      "min_count": 1.0})
+        dem.dist_edge_map(og, U, f, write_back, **kw)  # feeds the demand
+        rep.refresh()  # elected now: the measured round does not re-elect
+        replicas = rep.replicas
+        assert replicas.hot_ids.size
+    est = dem._estimate_mode_costs(og, sess, U.indices, replicas,
+                                   dedup)[mode].report
+
+    made, seen = [], []
+
+    class Recording(CostAccumulator):
+        def __init__(self, num_machines):
+            super().__init__(num_machines)
+            made.append(self)
+
+    real_span = dem.span
+
+    @contextlib.contextmanager
+    def spy(name, **args):
+        with real_span(name, **args):
+            yield
+        if name == "edgemap.propagate":
+            seen.append(copy.deepcopy(made[-1]._open))
+
+    monkeypatch.setattr(dem, "CostAccumulator", Recording)
+    monkeypatch.setattr(dem, "span", spy)
+    _, stats = dem.dist_edge_map(og, U, f, write_back, **kw)
+    assert stats.mode == mode and len(seen) == 1
+    assert_cost_parity(est, StageReport(P, seen))
 
 
 # ---------------------------------------------------------------------------
